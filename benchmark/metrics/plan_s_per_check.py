@@ -1,0 +1,10 @@
+"""Planning seconds per window check: the system's ``checker.plan``
+spans (the rung ladder, the plan gate and the pool seeding), summed over
+the window's checks and divided by their number. None unless every
+window check holds exactly one ``checker.search`` span."""
+
+from harness import checkspans
+
+
+def read(run):
+    return checkspans.seconds_per_check(run, "checker.plan")

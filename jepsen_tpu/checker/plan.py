@@ -22,8 +22,8 @@ int-width wraparound. This module evaluates them *ahead of time*:
 * **abstract evaluation** — each bucket's jit factory is traced with
   ``jax.eval_shape`` over ``ShapeDtypeStruct`` inputs (zero XLA
   compiles, zero device executions) and optionally priced with the
-  ``lower()``-only XLA cost analysis — the same lowering-no-compile
-  discipline as :func:`~jepsen_tpu.checker.tpu._shape_cost`;
+  ``lower()``-only XLA cost analysis (:func:`_cost_analysis`: lowering,
+  no compile);
 * **footprint math** — the packed-column bytes (exactly
   :func:`~jepsen_tpu.checker.tpu._cols_nbytes`), the search carry
   (exactly :func:`~jepsen_tpu.checker.tpu._carry0_host`), and a
@@ -495,13 +495,30 @@ def _carry_structs(cand: Candidate, jax) -> tuple:
             S((C,), np.int32), S((C,), np.int32), S((C,), np.bool_))
 
 
+def _cost_analysis(fn, args) -> Optional[Dict[str, float]]:
+    """``fn.lower(*args).cost_analysis()`` normalized to
+    ``{"flops", "bytes-accessed"}`` floats; None when the backend or
+    jax version does not support it. The HLO analysis counts a while
+    body once, so for the search executables it reads per level."""
+    ca = fn.lower(*args).cost_analysis()
+    if isinstance(ca, (list, tuple)):
+        ca = ca[0] if ca else None
+    if not isinstance(ca, dict):
+        return None
+    flops = float(ca.get("flops", 0.0) or 0.0)
+    byts = float(ca.get("bytes accessed", 0.0) or 0.0)
+    if flops <= 0 and byts <= 0:
+        return None
+    return {"flops": flops, "bytes-accessed": byts}
+
+
 def trace_candidate(cand: Candidate, kernel, cost: bool = False,
                     mesh=None) -> Dict[str, Any]:
     """Abstractly evaluate one candidate's jit factory: ``jax.eval_shape``
     proves the bucket traces (shape errors surface here, with zero XLA
     compiles and zero device executions), and with ``cost=True`` the
     ``lower()``-only XLA cost analysis predicts per-level flops /
-    bytes-accessed — the same no-compile discipline as ``_shape_cost``.
+    bytes-accessed (:func:`_cost_analysis`, no compile).
 
     Returns ``{"ok", "error", "cost"}``; memoized per bucket. A sharded
     candidate needs a real mesh to trace (with_sharding_constraint); when
@@ -550,7 +567,7 @@ def trace_candidate(cand: Candidate, kernel, cost: bool = False,
             jax.eval_shape(fn, *args)
             if cost:
                 try:
-                    return T._cost_analysis(fn, args)
+                    return _cost_analysis(fn, args)
                 except Exception:  # noqa: BLE001 — cost is best-effort
                     return None
             return None

@@ -853,45 +853,6 @@ def compile_line(delta: Dict[str, Any],
 #: run once in this process — the compile/execute phase separator.
 _EXECUTED_SHAPES: set = set()
 
-#: Shape key -> XLA cost-model dict (or None when unavailable): the
-#: per-executable flops / bytes-accessed accounting. Memoized per
-#: process — the cost comes from LOWERING only (no second XLA compile),
-#: and the HLO analysis counts a while body once, so for the search
-#: executables the numbers read as per-LEVEL model cost.
-_COST_BY_SHAPE: Dict[tuple, Optional[Dict[str, float]]] = {}
-
-
-def _cost_analysis(fn, args) -> Optional[Dict[str, float]]:
-    """``fn.lower(*args).cost_analysis()`` normalized to
-    ``{"flops", "bytes-accessed"}`` floats; None when the backend or
-    jax version does not support it (the accounting is best-effort —
-    a CPU-only run must behave identically without it)."""
-    ca = fn.lower(*args).cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    if not isinstance(ca, dict):
-        return None
-    flops = float(ca.get("flops", 0.0) or 0.0)
-    byts = float(ca.get("bytes accessed", 0.0) or 0.0)
-    if flops <= 0 and byts <= 0:
-        return None
-    return {"flops": flops, "bytes-accessed": byts}
-
-
-def _shape_cost(key: tuple, fn, args) -> Optional[Dict[str, float]]:
-    """Memoized per-executable cost model for one jit cache key +
-    padded shape. Never raises; a failed analysis memoizes None so the
-    lowering is not retried every segment."""
-    if key in _COST_BY_SHAPE:
-        cost = _COST_BY_SHAPE[key]
-    else:
-        try:
-            cost = _cost_analysis(fn, args)
-        except Exception:  # noqa: BLE001 — cost accounting is optional
-            cost = None
-        _COST_BY_SHAPE[key] = cost
-    return dict(cost) if cost else None
-
 
 def _first_call(key: tuple) -> bool:
     """True iff this executable shape has not run in this process yet.
@@ -1393,8 +1354,9 @@ def _prep_single(p: PackedHistory,
     if p.n_required == 0:
         return None, {"valid": True, "levels": 0, "backend": "tpu"}
     cr = _crash_width(p.n - p.n_required)
-    cols = (None if cr is None
-            else _split_packed(p, _bucket(p.n_required), cr, kernel))
+    with obs.span("checker.pack"):
+        cols = (None if cr is None
+                else _split_packed(p, _bucket(p.n_required), cr, kernel))
     if cols is None:
         return None, {
             "valid": UNKNOWN, "backend": "tpu",
@@ -1447,11 +1409,6 @@ def check_packed_tpu(p: PackedHistory, kernel: KernelSpec,
     cols, early = _prep_single(p, kernel)
     if early is not None:
         return early
-    if capacity is not None:
-        _check_window(window or WINDOW)
-        ladder = ((capacity, window or WINDOW, expand),)
-    else:
-        ladder = _ladder_for(_window_needed(p))
     # Mandatory pre-search plan gate (doc/plan.md), next to the PR-3
     # history gate: prove every rung fits the byte budget and encodes
     # inside int32 BEFORE any jit factory is touched. Invalid rungs are
@@ -1460,26 +1417,28 @@ def check_packed_tpu(p: PackedHistory, kernel: KernelSpec,
     # Kill switch: JTPU_PLAN_GATE=0.
     from jepsen_tpu.checker import plan as plan_mod
     plan_entry = None
-    if plan_mod.gate_enabled():
-        ladder, plan_entry = plan_mod.gate_ladder(
-            p, kernel, ladder, kind="single",
-            explicit=capacity is not None,
-            where="the monolithic device search")
-    out: Dict[str, Any] = {}
-    work: list = []
-    cost_entries: list = []
+    with obs.span("checker.plan"):
+        if capacity is not None:
+            _check_window(window or WINDOW)
+            ladder = ((capacity, window or WINDOW, expand),)
+        else:
+            ladder = _ladder_for(_window_needed(p))
+        if plan_mod.gate_enabled():
+            ladder, plan_entry = plan_mod.gate_ladder(
+                p, kernel, ladder, kind="single",
+                explicit=capacity is not None,
+                where="the monolithic device search")
     # Opt-in device profiling (doc/observability.md "Device
     # profiling"): a no-op unless JTPU_PROF=1 and a run dir is armed.
     with obs_profiler.capture():
-        out = _check_packed_ladder(p, kernel, ladder, cols, plan_entry,
-                                   work, cost_entries)
-    return out
+        return _check_packed_ladder(p, kernel, ladder, cols, plan_entry)
 
 
-def _check_packed_ladder(p, kernel, ladder, cols, plan_entry, work,
-                         cost_entries) -> Dict[str, Any]:
+def _check_packed_ladder(p, kernel, ladder, cols,
+                         plan_entry) -> Dict[str, Any]:
     from jepsen_tpu.obs import searchstats as obs_searchstats
     out: Dict[str, Any] = {}
+    work: list = []
     # Search analytics (doc/observability.md): with tracing on, the
     # single-history executable carries the per-level counter lane and
     # returns it as a 9th output; JTPU_TRACE=0 keeps the stats-off
@@ -1516,14 +1475,6 @@ def _check_packed_ladder(p, kernel, ladder, cols, plan_entry, work,
         out["work"] = list(work)
         if plan_entry is not None:
             out["plan"] = plan_entry
-        if obs.enabled():
-            cost = _shape_cost(shape_key, fn, [cols[c] for c in _COLS])
-            if cost:
-                cost_entries.append(dict(
-                    kind="single", rung=[cap, win, exp], unroll=unroll,
-                    levels=int(levels), **cost))
-        if cost_entries:
-            out["cost"] = [dict(e) for e in cost_entries]
         if slog is not None:
             # roll the counter log up into the result (and, when a run
             # directory is attached, searchstats.json + the live bits)
@@ -2083,8 +2034,9 @@ def check_packed_sharded(p: PackedHistory, kernel: KernelSpec,
     from jepsen_tpu.checker import plan as plan_mod
     plan_entry = None
     if plan_mod.gate_enabled():
-        plan_entry = plan_mod.gate_sharded(p, kernel, naxis, capacity,
-                                           window, expand)
+        with obs.span("checker.plan"):
+            plan_entry = plan_mod.gate_sharded(p, kernel, naxis, capacity,
+                                               window, expand)
     if capacity % naxis or expand % naxis:
         raise ValueError(
             f"the mesh axis ({naxis}) must divide capacity "
@@ -2128,15 +2080,6 @@ def check_packed_sharded(p: PackedHistory, kernel: KernelSpec,
             balance = _shard_balance(pool, naxis)
             if balance is not None:
                 out["shard-balance"] = balance
-        if obs.enabled():
-            # lowered INSIDE the mesh context: the search body carries
-            # with_sharding_constraint, which needs the mesh to trace
-            cost = _shape_cost(shape_key, fn, [cols[c] for c in _COLS])
-            if cost:
-                out["cost"] = [dict(
-                    kind="sharded", rung=[capacity, window, expand],
-                    unroll=_unroll_factor(), levels=int(levels),
-                    axis=naxis, **cost)]
     out["pool-sharding"] = f"{POOL_AXIS}={naxis}"
     if plan_entry is not None:
         out["plan"] = plan_entry
@@ -2237,16 +2180,28 @@ def check_history_sharded(history: History, model: Model,
     """Pack + pool-sharded check (see check_packed_sharded). None when
     the model has no integer kernel. Gated like check_history_tpu: a
     malformed history is rejected before packing or compilation."""
+    with obs.span("checker.search", kind="single", ops=len(history),
+                  keys=1):
+        pk = _lint_and_pack(history, model,
+                            "the pool-sharded device search")
+        if pk is None:
+            return None
+        packed, kernel = pk
+        return check_packed_sharded(packed, kernel, mesh, **kwargs)
+
+
+def _lint_and_pack(history: History, model: Model, where: str):
+    """The single-history preamble: the mandatory history gate, then
+    the packed encoding. ``(packed, kernel)``, or None when the model
+    has no integer kernel or an op is not encodable."""
     from jepsen_tpu.analysis.history_lint import gate_history
-    gate_history(history, where="the pool-sharded device search")
-    try:
-        pk = pack_with_init(history, model)
-    except ValueError:
-        return None
-    if pk is None:
-        return None
-    packed, kernel = pk
-    return check_packed_sharded(packed, kernel, mesh, **kwargs)
+    with obs.span("checker.lint"):
+        gate_history(history, where=where)
+    with obs.span("checker.pack"):
+        try:
+            return pack_with_init(history, model)
+        except ValueError:  # op f unsupported by the integer kernel
+            return None
 
 
 def warm_ladder(p: PackedHistory, kernel: KernelSpec,
@@ -2283,18 +2238,17 @@ def check_history_tpu(history: History, model: Model,
     """
     if window is not None:
         _check_window(window)
-    from jepsen_tpu.analysis.history_lint import gate_history
-    gate_history(history, where="the packed device search")
-    try:
-        pk = pack_with_init(history, model)
-    except ValueError:  # op f unsupported by the integer kernel
-        return None
-    if pk is None:
-        return None
-    packed, kernel = pk
-    return check_packed_tpu(packed, kernel, capacity, window, expand,
-                            segment_iters=segment_iters,
-                            deadline_s=deadline_s)
+    # the root span of the check: every lint, pack, plan, segment and
+    # device span of it nests under this one (doc/observability.md)
+    with obs.span("checker.search", kind="single", ops=len(history),
+                  keys=1):
+        pk = _lint_and_pack(history, model, "the packed device search")
+        if pk is None:
+            return None
+        packed, kernel = pk
+        return check_packed_tpu(packed, kernel, capacity, window, expand,
+                                segment_iters=segment_iters,
+                                deadline_s=deadline_s)
 
 
 def check_keyed_tpu(keyed: Dict[Any, Sequence], model: Model,
@@ -2321,134 +2275,153 @@ def check_keyed_tpu(keyed: Dict[Any, Sequence], model: Model,
     kernel = kernel_spec_for(model)
     if kernel is None:
         raise ValueError(f"model {model!r} has no integer kernel")
+    ops = sum(len(h) for h in keyed.values())
+    with obs.span("checker.search", kind="keyed", ops=ops,
+                  keys=len(keyed)):
+        return _check_keyed(keyed, model, kernel, ops, capacity, window,
+                            mesh, axis, expand, ladder)
+
+
+def _check_keyed(keyed, model, kernel, ops, capacity, window, mesh, axis,
+                 expand, ladder) -> Dict[str, Any]:
+    """check_keyed_tpu's body, under its ``checker.search`` span: one
+    lint pass, one pack pass and one plan pass over the whole batch,
+    then the batch ladder."""
     keys = list(keyed.keys())
     if not keys:
         return {"valid": True, "results": {}, "backend": "tpu"}
     results: Dict[Any, Dict[str, Any]] = {}
     packed: Dict[Any, PackedHistory] = {}
-    cost_entries: list = []
     from jepsen_tpu.analysis import summarize
     from jepsen_tpu.analysis.history_lint import (MalformedHistoryError,
                                                   gate_history)
-    for k in keys:
-        try:
-            # Per-key pre-search gate: a malformed key goes UNKNOWN
-            # with rule ids (the batch must not abort, matching the
-            # per-key encode-failure contract below), and never reaches
-            # the packed encoder or a compilation.
-            gate_history(keyed[k], where=f"the keyed device search "
-                                         f"(key {k!r})")
-            packed[k] = pack_with_init(keyed[k], model, kernel)[0]
-        except MalformedHistoryError as e:
-            results[k] = {"valid": UNKNOWN, "backend": "tpu",
-                          "error": str(e),
-                          "lint": summarize(e.findings)}
-        except ValueError as e:
-            # One key with an op the integer kernel can't encode must not
-            # abort the batch; the caller can fall back per key.
-            results[k] = {"valid": UNKNOWN, "backend": "tpu",
-                          "error": str(e)}
+    # Per-key pre-search gate: a malformed key goes UNKNOWN with rule
+    # ids (the batch must not abort, matching the per-key encode-failure
+    # contract below), and never reaches the packed encoder or a
+    # compilation.
+    linted = []
+    with obs.span("checker.lint", keys=len(keys), ops=ops):
+        for k in keys:
+            try:
+                gate_history(keyed[k], where=f"the keyed device search "
+                                             f"(key {k!r})")
+            except MalformedHistoryError as e:
+                results[k] = {"valid": UNKNOWN, "backend": "tpu",
+                              "error": str(e),
+                              "lint": summarize(e.findings)}
+            else:
+                linted.append(k)
+    with obs.span("checker.pack", keys=len(linted)):
+        for k in linted:
+            try:
+                packed[k] = pack_with_init(keyed[k], model, kernel)[0]
+            except ValueError as e:
+                # One key with an op the integer kernel can't encode must
+                # not abort the batch; the caller can fall back per key.
+                results[k] = {"valid": UNKNOWN, "backend": "tpu",
+                              "error": str(e)}
 
-    # Common padded required width across the batch, so compilations are
-    # shared. The CRASHED width is per-key-cohort, not batch-wide: the
-    # crash grids and the subset-dominance passes are ~2x of per-level
-    # cost, and one crashy key must not levy that on a mostly crash-free
-    # batch (measured 64x500 dense with 8/64 crashy keys: 3.3 s
-    # batch-wide vs ~1.9 s cohorted on the CPU backend). A key with more
-    # crashed ops than the bitmask holds goes UNKNOWN alone (per-key
-    # split failure), not the whole batch.
-    breq = _bucket(max((p.n_required for p in packed.values()),
-                       default=1) or 1)
+        # Common padded required width across the batch, so compilations are
+        # shared. The CRASHED width is per-key-cohort, not batch-wide: the
+        # crash grids and the subset-dominance passes are ~2x of per-level
+        # cost, and one crashy key must not levy that on a mostly crash-free
+        # batch (measured 64x500 dense with 8/64 crashy keys: 3.3 s
+        # batch-wide vs ~1.9 s cohorted on the CPU backend). A key with more
+        # crashed ops than the bitmask holds goes UNKNOWN alone (per-key
+        # split failure), not the whole batch.
+        breq = _bucket(max((p.n_required for p in packed.values()),
+                           default=1) or 1)
 
-    # rows: (key, cols, window_needed, max_cap_tried, max_win_tried,
-    # forced_frac, crash_width) — the tried maxima keep escalation
-    # monotone: a key that overflowed a 16384 pool must not re-run on a
-    # later rung whose capacity AND window are both no larger (e.g. the
-    # wide tail's 512 rung, which exists for deferred wide keys, not
-    # lossy narrow ones).
-    rows = []
-    for key, p in packed.items():
-        if p.n_required == 0:
-            results[key] = {"valid": True, "levels": 0, "backend": "tpu"}
-            continue
-        crw = _crash_width(p.n - p.n_required)
-        cols = (None if crw is None
-                else _split_packed(p, breq, crw, kernel))
-        if cols is None:
-            results[key] = {
-                "valid": UNKNOWN, "backend": "tpu",
-                "error": f"{p.n - p.n_required} crashed ops exceed the "
-                         f"crashed-set width {CRASH_MAX}"}
-            continue
-        # forced fraction: how much of the key's required section is
-        # forced runs (fr=1). Staggered workloads (~0.9) ride the
-        # fast-forward and want the slim first rung; dense workloads
-        # (~0.05) want a fatter expansion — the auto ladder starts them
-        # one rung later (see the dense rung below).
-        nr_ = p.n_required
-        ffrac = float(cols["fr"][:nr_].sum()) / nr_
-        rows.append((key, cols, _window_needed(p), 0, 0, ffrac, crw, []))
+        # rows: (key, cols, window_needed, max_cap_tried, max_win_tried,
+        # forced_frac, crash_width) — the tried maxima keep escalation
+        # monotone: a key that overflowed a 16384 pool must not re-run on a
+        # later rung whose capacity AND window are both no larger (e.g. the
+        # wide tail's 512 rung, which exists for deferred wide keys, not
+        # lossy narrow ones).
+        rows = []
+        for key, p in packed.items():
+            if p.n_required == 0:
+                results[key] = {"valid": True, "levels": 0, "backend": "tpu"}
+                continue
+            crw = _crash_width(p.n - p.n_required)
+            cols = (None if crw is None
+                    else _split_packed(p, breq, crw, kernel))
+            if cols is None:
+                results[key] = {
+                    "valid": UNKNOWN, "backend": "tpu",
+                    "error": f"{p.n - p.n_required} crashed ops exceed the "
+                             f"crashed-set width {CRASH_MAX}"}
+                continue
+            # forced fraction: how much of the key's required section is
+            # forced runs (fr=1). Staggered workloads (~0.9) ride the
+            # fast-forward and want the slim first rung; dense workloads
+            # (~0.05) want a fatter expansion — the auto ladder starts them
+            # one rung later (see the dense rung below).
+            nr_ = p.n_required
+            ffrac = float(cols["fr"][:nr_].sum()) / nr_
+            rows.append((key, cols, _window_needed(p), 0, 0, ffrac, crw, []))
 
-    adaptive = False
-    if ladder is not None:
-        # caller-supplied escalation rungs (tests, dryruns: small rungs
-        # keep compile cost bounded while still exercising escalation)
-        if capacity is not None or expand is not None:
+    with obs.span("checker.plan"):
+        adaptive = False
+        if ladder is not None:
+            # caller-supplied escalation rungs (tests, dryruns: small rungs
+            # keep compile cost bounded while still exercising escalation)
+            if capacity is not None or expand is not None:
+                raise ValueError(
+                    "pass either ladder= or capacity=/expand=, not both: "
+                    "an explicit ladder replaces the whole escalation "
+                    "schedule and would silently ignore them")
+            for _, win, _ in ladder:
+                _check_window(win)
+        elif capacity is not None:
+            _check_window(window or WINDOW)
+            ladder = ((capacity, window or WINDOW, expand),)
+        else:
+            # capacity ladder at the narrow window first (most keys), then
+            # the expansion-heavy wide rungs the per-row deferral routes
+            # wide keys to (see WIDE_LADDER). Between the slim first rung
+            # and the escalations sits the DENSE rung (same capacity, double
+            # expansion): keys with a low forced fraction skip the slim rung
+            # and start there — measured on 64x500 CAS batches (CPU backend):
+            # dense 5.7 s -> 3.4 s at (32,8) while staggered stays on (32,4)
+            # at 0.20 s instead of doubling to 0.42 s.
+            lad0 = _capacity_ladder()
+            (cap0, exp0) = lad0[0]
+            adaptive = True
+            ladder = (((cap0, 32, exp0), (cap0, 32, max(8, exp0 * 2)))
+                      + tuple((c, 32, e) for c, e in lad0[1:])
+                      + ((512, 64, 512), (4096, 128, 1024),
+                         (16384, 128, 4096)))
+
+        # Pre-search plan gate over the batch's escalation schedule: dims
+        # aggregate over the keys (widest required section, crashiest key,
+        # widest needed window, K-fold footprint); rungs that cannot fit or
+        # encode are filtered before any batch executable is built, and the
+        # rejections land in the result's "plan" entry.
+        from jepsen_tpu.checker import plan as plan_mod
+        plan_entry = None
+        if rows and plan_mod.gate_enabled():
+            dims = plan_mod.PlanDims(
+                n_required=max(packed[r[0]].n_required for r in rows),
+                n_crashed=max(packed[r[0]].n - packed[r[0]].n_required
+                              for r in rows),
+                window_needed=max(r[2] for r in rows),
+                keys=len(rows))
+            ladder, plan_entry = plan_mod.gate_ladder(
+                dims, kernel, ladder, kind="batch",
+                explicit=capacity is not None, keys=len(rows),
+                where="the keyed device search")
+
+        # First rung: hash tie-break (diversified beam — measured 2.4x on
+        # dense key batches; a bad draw just escalates). Later rungs use the
+        # deterministic lex order, as do single-rung ladders (where a lossy
+        # draw would have NO lex escalation to fall back to) unless an
+        # explicit JTPU_TIEBREAK0=hash asked for the diversified beam anyway
+        # (bench sweeps need the override honored even on pinned rungs).
+        tb_env = _os_environ_get("JTPU_TIEBREAK0")
+        if tb_env not in (None, "lex", "hash"):
             raise ValueError(
-                "pass either ladder= or capacity=/expand=, not both: "
-                "an explicit ladder replaces the whole escalation "
-                "schedule and would silently ignore them")
-        for _, win, _ in ladder:
-            _check_window(win)
-    elif capacity is not None:
-        _check_window(window or WINDOW)
-        ladder = ((capacity, window or WINDOW, expand),)
-    else:
-        # capacity ladder at the narrow window first (most keys), then
-        # the expansion-heavy wide rungs the per-row deferral routes
-        # wide keys to (see WIDE_LADDER). Between the slim first rung
-        # and the escalations sits the DENSE rung (same capacity, double
-        # expansion): keys with a low forced fraction skip the slim rung
-        # and start there — measured on 64x500 CAS batches (CPU backend):
-        # dense 5.7 s -> 3.4 s at (32,8) while staggered stays on (32,4)
-        # at 0.20 s instead of doubling to 0.42 s.
-        lad0 = _capacity_ladder()
-        (cap0, exp0) = lad0[0]
-        adaptive = True
-        ladder = (((cap0, 32, exp0), (cap0, 32, max(8, exp0 * 2)))
-                  + tuple((c, 32, e) for c, e in lad0[1:])
-                  + ((512, 64, 512), (4096, 128, 1024),
-                     (16384, 128, 4096)))
-
-    # Pre-search plan gate over the batch's escalation schedule: dims
-    # aggregate over the keys (widest required section, crashiest key,
-    # widest needed window, K-fold footprint); rungs that cannot fit or
-    # encode are filtered before any batch executable is built, and the
-    # rejections land in the result's "plan" entry.
-    from jepsen_tpu.checker import plan as plan_mod
-    plan_entry = None
-    if rows and plan_mod.gate_enabled():
-        dims = plan_mod.PlanDims(
-            n_required=max(packed[r[0]].n_required for r in rows),
-            n_crashed=max(packed[r[0]].n - packed[r[0]].n_required
-                          for r in rows),
-            window_needed=max(r[2] for r in rows),
-            keys=len(rows))
-        ladder, plan_entry = plan_mod.gate_ladder(
-            dims, kernel, ladder, kind="batch",
-            explicit=capacity is not None, keys=len(rows),
-            where="the keyed device search")
-
-    # First rung: hash tie-break (diversified beam — measured 2.4x on
-    # dense key batches; a bad draw just escalates). Later rungs use the
-    # deterministic lex order, as do single-rung ladders (where a lossy
-    # draw would have NO lex escalation to fall back to) unless an
-    # explicit JTPU_TIEBREAK0=hash asked for the diversified beam anyway
-    # (bench sweeps need the override honored even on pinned rungs).
-    tb_env = _os_environ_get("JTPU_TIEBREAK0")
-    if tb_env not in (None, "lex", "hash"):
-        raise ValueError(
-            f"JTPU_TIEBREAK0 must be lex|hash, got {tb_env!r}")
+                f"JTPU_TIEBREAK0 must be lex|hash, got {tb_env!r}")
 
     # Opt-in device profiling across the whole batch escalation (one
     # capture, not one per rung); no-op unless JTPU_PROF=1 + a run dir.
@@ -2456,9 +2429,9 @@ def check_keyed_tpu(keyed: Dict[Any, Sequence], model: Model,
     _prof.__enter__()
     devices: set = set()
     try:
-        results, cost_entries = _keyed_ladder(
+        results = _keyed_ladder(
             ladder, rows, adaptive, tb_env, mesh, axis, packed, breq,
-            kernel, results, cost_entries, devices)
+            kernel, results, devices)
     finally:
         _prof.__exit__(None, None, None)
     valid = True
@@ -2474,16 +2447,11 @@ def check_keyed_tpu(keyed: Dict[Any, Sequence], model: Model,
            "devices": sorted(devices)}
     if plan_entry is not None:
         out["plan"] = plan_entry
-    if cost_entries:
-        # one entry per batch executable actually launched (keys share
-        # it), at the TOP level — attaching the batch cost to every key
-        # result would overcount the work len(grp)-fold
-        out["cost"] = cost_entries
     return out
 
 
 def _keyed_ladder(ladder, rows, adaptive, tb_env, mesh, axis, packed,
-                  breq, kernel, results, cost_entries, devices):
+                  breq, kernel, results, devices):
     """The keyed batch's escalation loop (split out so the profiler
     capture wraps exactly the device work). Adds the ids of the devices
     each launch's outputs lived on to ``devices``."""
@@ -2596,29 +2564,23 @@ def _keyed_ladder(ladder, rows, adaptive, tb_env, mesh, axis, packed,
                 rung=(cap, win, exp), keys=len(grp),
                 crash_width=crw, tiebreak=tb)
             devices.update(d.id for d in outs[0].sharding.device_set)
-            if multiproc:
-                # Per-key verdict rows live on their owning host; gather
-                # the scalar verdict vectors so every process takes
-                # identical host-side decisions (escalation retries stay
-                # SPMD-deterministic).
-                from jax.experimental import multihost_utils
-                scalars = tuple(
-                    multihost_utils.process_allgather(x, tiled=True)
-                    for x in outs[:5])
-            else:
-                scalars = outs[:5]
-            done, lossy, wovf, best, levels = (np.asarray(x)
-                                               for x in scalars)
+            with obs.span("checker.readback", keys=len(grp)):
+                if multiproc:
+                    # Per-key verdict rows live on their owning host;
+                    # gather the scalar verdict vectors so every process
+                    # takes identical host-side decisions (escalation
+                    # retries stay SPMD-deterministic).
+                    from jax.experimental import multihost_utils
+                    scalars = tuple(
+                        multihost_utils.process_allgather(x, tiled=True)
+                        for x in outs[:5])
+                else:
+                    scalars = outs[:5]
+                done, lossy, wovf, best, levels = (np.asarray(x)
+                                                   for x in scalars)
             # a vmapped batch advances every key per program level, so
             # the device executed the slowest key's level count
             _LEVELS_TOTAL.inc(int(levels.max(initial=0)))
-            if obs.enabled():
-                cost = _shape_cost(shape_key, fn, arrays)
-                if cost:
-                    cost_entries.append(dict(
-                        kind="batch", rung=[cap, win, exp],
-                        unroll=unroll, keys=len(grp), crash_width=crw,
-                        levels=int(levels.max(initial=0)), **cost))
             # Pool columns ([capacity] rows per key) are only read for
             # clean refutations — don't ship up to 16384 ints/key
             # off-device (and over DCN) for the common all-valid rung.
@@ -2627,13 +2589,15 @@ def _keyed_ladder(ladder, rows, adaptive, tb_env, mesh, axis, packed,
             refuted = ~done & ~lossy & ~wovf
             pk = ps = pa = None
             if refuted.any():
-                pools = outs[5:]
-                if multiproc:
-                    from jax.experimental import multihost_utils
-                    pools = tuple(
-                        multihost_utils.process_allgather(x, tiled=True)
-                        for x in pools)
-                pk, ps, pa = (np.asarray(x) for x in pools)
+                with obs.span("checker.readback", keys=len(grp),
+                              pools=True):
+                    pools = outs[5:]
+                    if multiproc:
+                        from jax.experimental import multihost_utils
+                        pools = tuple(
+                            multihost_utils.process_allgather(x, tiled=True)
+                            for x in pools)
+                    pk, ps, pa = (np.asarray(x) for x in pools)
             for r, (key, cols, wneed, mcap, mwin, ffrac, _, work) in \
                     enumerate(grp):
                 res = _result(bool(done[r]), bool(lossy[r]),
@@ -2656,4 +2620,4 @@ def _keyed_ladder(ladder, rows, adaptive, tb_env, mesh, axis, packed,
                 else:
                     results[key] = res
         rows = retry
-    return results, cost_entries
+    return results

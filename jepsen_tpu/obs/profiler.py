@@ -11,8 +11,10 @@ the glue:
 * :func:`capture` — an **opt-in** (``JTPU_PROF=1`` / ``--profile``)
   context manager the device checkers wrap their searches in. It
   starts ``jax.profiler.start_trace`` into ``<run_dir>/profile/`` and
-  records a ``prof.capture`` host span over the captured region — the
-  clock anchor the merge below aligns on. Everything is
+  records a ``prof.capture`` host span over the captured region, with a
+  ``jtpu.sync`` profiler annotation inside it whose host clock reading
+  the span keeps (``sync_ts``) — the shared clock the merge below
+  aligns on. Everything is
   failure-tolerant: no jax, no profiler support on the platform, a
   capture that raises — all silent no-ops, and with profiling off (the
   default) no artifact differs by a byte from the pre-profiler tree
@@ -25,7 +27,7 @@ the glue:
   the capture, never the run (``tools/chaos_matrix.py --only
   prof-kill`` drills exactly that).
 * :func:`merge_into_host` — align the profiler's clock to the host
-  trace via the ``prof.capture`` anchor and parent each device record
+  trace via the ``jtpu.sync`` mark and parent each device record
   under the ``checker.segment`` / ``checker.device.*`` host span whose
   interval contains it, so the Perfetto export shows a device-track
   lane nested under the matching host span.
@@ -49,6 +51,7 @@ import logging
 import os
 import re
 import threading
+import time
 from typing import Any, Dict, List, Optional, Tuple
 
 from jepsen_tpu.obs import metrics as obs_metrics
@@ -59,9 +62,14 @@ log = logging.getLogger("jepsen.obs")
 #: The profile directory's name inside a run's store directory.
 PROFILE_DIRNAME = "profile"
 
-#: The host anchor span recorded over each captured region; the merge
-#: maps the capture's earliest device timestamp onto this span's start.
+#: The host anchor span recorded over each captured region.
 CAPTURE_SPAN = "prof.capture"
+
+#: The profiler annotation opened once at the start of each capture.
+#: ``time.monotonic_ns()`` read inside it is stored on the capture span
+#: as ``sync_ts`` (tracer-relative), and the annotation carries the
+#: span's sid as ``capture``: one instant named on both clocks.
+SYNC = "jtpu.sync"
 
 #: Host span names a device record may be parented under (deepest wins).
 HOST_PARENTS = ("checker.segment", "checker.device.single",
@@ -158,10 +166,16 @@ class _Capture:
                         "is a no-op on this platform", _FAILED)
             return self
         self.dir = target
-        # the clock anchor: a host span covering exactly the captured
-        # region, closed when the capture stops
+        # the anchor: a host span covering the captured region, closed
+        # when the capture stops, holding the host reading of the sync
+        # mark the profiler records in the same instant
         self.span = obs_trace.span(CAPTURE_SPAN, dir=PROFILE_DIRNAME)
         self.span.__enter__()
+        sid = getattr(self.span, "sid", None)
+        if sid is not None:
+            with jax.profiler.TraceAnnotation(SYNC, capture=sid):
+                mono = time.monotonic_ns()
+            self.span.set(sync_ts=mono - obs_trace.tracer().epoch_ns)
         return self
 
     def __exit__(self, *exc) -> bool:
@@ -212,8 +226,12 @@ def parse_trace(path: str) -> Tuple[List[dict], Dict[str, Any]]:
     """One profiler trace file -> (device records, stats). Device
     records are ``{"name", "ts", "dur", "lane", "track": "device"}``
     with ts/dur in **nanoseconds relative to the capture** (the
-    profiler emits microseconds). A truncated or corrupt file (SIGKILL
-    mid-write) degrades to ``([], {"error": ...})`` — never raises."""
+    profiler emits microseconds). When the capture holds the
+    :data:`SYNC` annotation (in any host lane), ``stats["sync"]`` is its
+    start on the same clock and every record carries it as ``sync``,
+    with the capture span's sid as ``capture``. A truncated or corrupt
+    file (SIGKILL mid-write) degrades to ``([], {"error": ...})`` —
+    never raises."""
     stats: Dict[str, Any] = {"events": 0, "device": 0}
     try:
         if path.endswith(".gz"):
@@ -254,6 +272,7 @@ def parse_trace(path: str) -> Tuple[List[dict], Dict[str, Any]]:
         return None
 
     out: List[dict] = []
+    sync = None
     for e in events:
         if e.get("ph") != "X":
             continue
@@ -261,17 +280,28 @@ def parse_trace(path: str) -> Tuple[List[dict], Dict[str, Any]]:
         name = str(e.get("name", "?"))
         if name.startswith("$"):        # python-tracer frames, not XLA
             continue
-        lane = lane_of(e)
-        if lane is None:
-            continue
         try:
             ts = int(float(e["ts"]) * 1e3)          # us -> ns
             dur = int(float(e.get("dur", 0)) * 1e3)
         except (KeyError, TypeError, ValueError):
             continue
+        lane = lane_of(e)
+        if lane is None:
+            if name == SYNC and sync is None:
+                sync = (ts, (e.get("args") or {}).get("capture"))
+            continue
         out.append({"name": name, "ts": ts, "dur": dur,
                     "lane": lane, "track": "device"})
         stats["device"] += 1
+    if sync is not None:
+        stats["sync"] = sync[0]
+        try:
+            capture = int(sync[1])
+        except (TypeError, ValueError):
+            capture = None
+        for r in out:
+            r["sync"] = sync[0]
+            r["capture"] = capture
     out.sort(key=lambda r: r["ts"])
     return out, stats
 
@@ -308,21 +338,34 @@ def merge_into_host(host_records: List[dict],
     """Shift device records onto the host trace's clock and parent
     each under the host span that contained it.
 
-    Alignment: the profiler's epoch is arbitrary, the host tracer's is
-    ``time.monotonic_ns`` at process start — but the ``prof.capture``
-    host span covers exactly the captured region, so mapping the
-    earliest device timestamp onto that span's start aligns the two
-    (both clocks are monotonic; drift over one capture is negligible
-    against kernel durations). Without an anchor span (legacy traces)
-    the earliest host span stands in.
+    Alignment: the profiler's epoch is arbitrary (each capture starts
+    its own), the host tracer's is ``time.monotonic_ns`` at process
+    start. Each capture names one instant on both clocks: the
+    :data:`SYNC` annotation's start in the profiler's (the record's
+    ``sync``) and the ``sync_ts`` of the ``prof.capture`` span whose
+    sid the annotation carries, so a record moves by the difference
+    (the benchmark harness aligns its own trace the same way). Only a
+    legacy capture, with no sync mark or no matching span, falls back
+    to mapping its earliest device timestamp onto the earliest capture
+    span's start (or, with none, the earliest host span's); that rule
+    shifts every record early by the host work between the capture's
+    start and its first device operation.
 
     Each device record then gets ``pid`` = the sid of the deepest
     :data:`HOST_PARENTS` span whose interval contains its midpoint
-    (fallback: the capture span), and a synthetic per-lane ``tid`` so
+    (fallback: its capture span), and a synthetic per-lane ``tid`` so
     the export renders device lanes as their own tracks. Returns the
     NEW records only (callers concatenate)."""
     if not device_records:
         return []
+    synced = {int(r["sid"]): r for r in host_records
+              if r.get("name") == CAPTURE_SPAN and "sync_ts" in r
+              and "sid" in r}
+
+    def sync_anchor(r):
+        return synced.get(r.get("capture")) if "sync" in r else None
+
+    legacy = [r for r in device_records if sync_anchor(r) is None]
     anchors = [r for r in host_records
                if r.get("name") == CAPTURE_SPAN]
     if anchors:
@@ -334,7 +377,8 @@ def merge_into_host(host_records: List[dict],
         anchor_sid = 0
     else:
         anchor_ts, anchor_sid = 0, 0
-    offset = anchor_ts - min(int(r["ts"]) for r in device_records)
+    legacy_offset = (anchor_ts - min(int(r["ts"]) for r in legacy)
+                     if legacy else 0)
 
     parents = sorted(
         ((int(r.get("ts", 0)), int(r.get("ts", 0)) + int(r.get("dur", 0)),
@@ -351,10 +395,14 @@ def merge_into_host(host_records: List[dict],
     lanes: Dict[str, int] = {}
     out: List[dict] = []
     for r in device_records:
-        ts = int(r["ts"]) + offset
+        cap = sync_anchor(r)
+        if cap is None:
+            ts, pid = int(r["ts"]) + legacy_offset, anchor_sid
+        else:
+            ts = int(r["ts"]) + int(cap["sync_ts"]) - int(r["sync"])
+            pid = int(cap["sid"])
         dur = int(r.get("dur", 0))
         mid = ts + dur // 2
-        pid = anchor_sid
         for lo, hi, sid in parents:
             if lo <= mid <= hi:
                 pid = sid

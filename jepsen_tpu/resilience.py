@@ -610,9 +610,8 @@ def supervised_check_packed(p: PackedHistory, kernel: KernelSpec,
       history) instead of starting over; ``checkpoint_path`` /
       ``on_checkpoint`` persist/observe checkpoints after each segment.
     * The result carries ``attempts`` (the supervision trail),
-      ``segments``, ``segment-iters``, and (with tracing on) ``cost``
-      — per-executable XLA cost-model entries — alongside the usual
-      telemetry keys.
+      ``segments`` and ``segment-iters`` alongside the usual telemetry
+      keys.
     * Live progress (level / frontier width / configs-per-s / ETA) is
       published to :mod:`jepsen_tpu.obs.observatory` after every
       segment — the ``watch`` CLI and ``/live`` endpoint surface.
@@ -654,11 +653,6 @@ def _supervised_check_packed(p: PackedHistory, kernel: KernelSpec,
     if deadline_s is None:
         deadline_s = _env_float("JTPU_SEGMENT_DEADLINE_S", 0.0) or None
     policy = policy or RetryPolicy()
-    if capacity is not None:
-        T._check_window(window or T.WINDOW)
-        ladder = ((capacity, window or T.WINDOW, expand),)
-    else:
-        ladder = T._ladder_for(T._window_needed(p))
     # Mandatory pre-search plan gate (doc/plan.md): an explicit rung
     # that cannot fit/shard/encode is rejected BEFORE any compilation;
     # auto-ladder rungs whose only problem is footprint stay in — the
@@ -667,11 +661,17 @@ def _supervised_check_packed(p: PackedHistory, kernel: KernelSpec,
     # and OOM-halving reactively. Kill switch: JTPU_PLAN_GATE=0.
     from jepsen_tpu.checker import plan as plan_mod
     plan_entry = None
-    if plan_mod.gate_enabled():
-        ladder, plan_entry = plan_mod.gate_ladder(
-            p, kernel, ladder, kind="segment",
-            explicit=capacity is not None, derate=capacity is None,
-            where="the supervised device search")
+    with obs.span("checker.plan"):
+        if capacity is not None:
+            T._check_window(window or T.WINDOW)
+            ladder = ((capacity, window or T.WINDOW, expand),)
+        else:
+            ladder = T._ladder_for(T._window_needed(p))
+        if plan_mod.gate_enabled():
+            ladder, plan_entry = plan_mod.gate_ladder(
+                p, kernel, ladder, kind="segment",
+                explicit=capacity is not None, derate=capacity is None,
+                where="the supervised device search")
     crw = T._crash_width(p.n - p.n_required) or 0
     cr_pad = cols["cf"].shape[0]
     lmax = T._level_budget(cols["f"].shape[0], cr_pad)
@@ -697,11 +697,6 @@ def _supervised_check_packed(p: PackedHistory, kernel: KernelSpec,
     frontier_hwm = 0
     transfer_bytes = 0
     cols_b = T._cols_nbytes(cols)
-    # Per-executable XLA cost-model entries (doc/observability.md):
-    # flops / bytes-accessed are per while-iteration (the HLO cost
-    # analysis counts a while body once), accumulated with the levels
-    # each shape actually ran — bench.py's utilization lines read this.
-    cost_entries: Dict[tuple, Dict[str, Any]] = {}
     # Pre-emptive OOM avoidance (obs/devices.py): below this headroom
     # ratio the pool halves BEFORE the allocator fails. Inert when the
     # backend exposes no memory stats (CPU) or the knob is <= 0.
@@ -729,9 +724,10 @@ def _supervised_check_packed(p: PackedHistory, kernel: KernelSpec,
                 # budget (JTPU_PLAN_BYTES_LIMIT / device bytes-limit) —
                 # the ahead-of-time twin of the reactive OOM halving.
                 # No-op when no limit is known (CPU) or the rung fits.
-                cap_s, exp_s, pred, blim = plan_mod.seed_rung(
-                    cap, win, exp, breq=cols["f"].shape[0], crw=cr_pad,
-                    floor=policy.min_capacity)
+                with obs.span("checker.plan"):
+                    cap_s, exp_s, pred, blim = plan_mod.seed_rung(
+                        cap, win, exp, breq=cols["f"].shape[0], crw=cr_pad,
+                        floor=policy.min_capacity)
                 if cap_s != cap_eff:
                     trail.append({"rung": (cap, win, exp),
                                   "effective": (cap_s, win, exp_s),
@@ -801,7 +797,6 @@ def _supervised_check_packed(p: PackedHistory, kernel: KernelSpec,
             phase = ("compile" if shape_key not in T._EXECUTED_SHAPES
                      else "execute")
             lvl0 = int(carry[8])
-            cost = None
             try:
                 if _inject_fault is not None:
                     _inject_fault(dict(ctx))
@@ -813,17 +808,6 @@ def _supervised_check_packed(p: PackedHistory, kernel: KernelSpec,
                               segment=seg_idx, level=lvl0,
                               rung=[cap_eff, win, exp_eff],
                               backend=ctx["backend"]) as sp:
-                    if obs.enabled():
-                        # per-shape XLA cost model (memoized; lowering
-                        # only, no second compile) — before t0 so the
-                        # segment clock stays a device measurement
-                        cost = T._shape_cost(
-                            shape_key, fn,
-                            [cols[c] for c in T._COLS]
-                            + [np.int32(seg), carry])
-                        if cost:
-                            sp.set(flops=cost["flops"],
-                                   bytes_accessed=cost["bytes-accessed"])
                     t0 = time.perf_counter()
                     carry = _call_segment(fn, cols, carry, seg,
                                           device=fallback,
@@ -943,14 +927,6 @@ def _supervised_check_packed(p: PackedHistory, kernel: KernelSpec,
                 T._TRANSFER_BYTES.inc(carry_b,
                                       direction="device-to-host")
                 transfer_bytes += cols_b + 2 * carry_b
-                if cost:
-                    ent = cost_entries.get(shape_key)
-                    if ent is None:
-                        ent = cost_entries[shape_key] = dict(
-                            kind="segment",
-                            rung=[cap_eff, win, exp_eff],
-                            unroll=unroll, levels=0, **cost)
-                    ent["levels"] += lvl1 - lvl0
                 # search analytics: the counter lane rows this segment
                 # advanced through, rolled into searchstats.json and the
                 # live dup-rate/truncation bits (host code BETWEEN
@@ -1021,10 +997,6 @@ def _supervised_check_packed(p: PackedHistory, kernel: KernelSpec,
             ss = obs_searchstats.rollup(np.asarray(carry[13])[:levels])
             out["searchstats"] = ss
             obs_searchstats.finalize(ss)
-        if cost_entries:
-            # per-executable XLA cost-model accounting: flops / bytes
-            # are per while-iteration, "levels" is what this shape ran
-            out["cost"] = [dict(e) for e in cost_entries.values()]
         if fallback is not None:
             out["backend-fallback"] = "cpu"
         if out["valid"] is not UNKNOWN:

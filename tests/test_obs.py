@@ -1,8 +1,8 @@
 """Observability subsystem (jepsen_tpu.obs): span tracer semantics,
 metrics registry math, export formats, run artifacts, the JTPU_TRACE
 kill switch, the /metrics + /live endpoints, and the search
-observatory (live progress, device memory accounting, XLA cost
-accounting). Tier-1 under the ``obs`` marker (doc/observability.md is
+observatory (live progress, device memory accounting, shard balance).
+Tier-1 under the ``obs`` marker (doc/observability.md is
 the operator view)."""
 
 import json
@@ -558,74 +558,11 @@ class TestWatchCLI:
 
 
 # ---------------------------------------------------------------------------
-# XLA cost accounting
+# Shard balance accounting
 # ---------------------------------------------------------------------------
 
 
 class TestCostAccounting:
-    def _check(self, **kw):
-        from jepsen_tpu.models import CASRegister
-        from jepsen_tpu.ops.encode import pack_with_init
-        from jepsen_tpu.resilience import supervised_check_packed
-        from jepsen_tpu.testing import simulate_register_history
-        h = simulate_register_history(150, n_procs=5, n_vals=4, seed=3)
-        p, kernel = pack_with_init(h, CASRegister())
-        return supervised_check_packed(p, kernel, capacity=64,
-                                       expand=8, segment_iters=8, **kw)
-
-    def test_supervised_result_carries_per_executable_cost(self):
-        r = self._check()
-        assert r["valid"] is True
-        (ent,) = r["cost"]
-        assert ent["kind"] == "segment"
-        assert ent["flops"] > 0 and ent["bytes-accessed"] > 0
-        assert ent["levels"] == r["levels"]
-        assert ent["rung"] == [64, 32, 8]
-        seg_spans = [s for s in obs.tracer().spans()
-                     if s["name"] == "checker.segment"]
-        assert seg_spans and seg_spans[-1]["flops"] == ent["flops"]
-
-    def test_monolithic_and_keyed_carry_cost(self):
-        from jepsen_tpu.checker.tpu import (check_history_tpu,
-                                            check_keyed_tpu)
-        from jepsen_tpu.models import CASRegister
-        from jepsen_tpu.testing import simulate_register_history
-        h = simulate_register_history(150, n_procs=5, n_vals=4, seed=3)
-        r = check_history_tpu(h, CASRegister(), segment_iters=0)
-        assert r["cost"][0]["kind"] == "single"
-        assert r["cost"][0]["flops"] > 0
-        keyed = {k: simulate_register_history(60, n_procs=3, n_vals=4,
-                                              seed=500 + k)
-                 for k in range(3)}
-        rk = check_keyed_tpu(keyed, CASRegister())
-        assert rk["valid"] is True
-        assert rk["cost"] and rk["cost"][0]["kind"] == "batch"
-        assert rk["cost"][0]["keys"] == 3
-        # the batch executable's cost lives at the TOP level only —
-        # attaching it per key would overcount the work keys-fold
-        assert all("cost" not in res
-                   for res in rk["results"].values())
-
-    def test_cost_absent_with_trace_off(self, monkeypatch):
-        monkeypatch.setenv("JTPU_TRACE", "0")
-        r = self._check()
-        assert r["valid"] is True
-        assert "cost" not in r
-
-    def test_cost_analysis_failure_degrades_silently(self, monkeypatch):
-        # a backend/jax without cost_analysis: verdicts unchanged, no
-        # cost key, no exception — the tier-1 degradation contract
-        from jepsen_tpu.checker import tpu as T
-
-        def boom(fn, args):
-            raise AttributeError("no cost_analysis on this backend")
-
-        monkeypatch.setattr(T, "_cost_analysis", boom)
-        monkeypatch.setattr(T, "_COST_BY_SHAPE", {})
-        r = self._check()
-        assert r["valid"] is True
-        assert "cost" not in r
-
     def test_shard_balance_accounting(self):
         import numpy as np
         from jepsen_tpu.checker.tpu import _shard_balance

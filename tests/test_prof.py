@@ -124,6 +124,101 @@ class TestProfilerOptIn:
         assert obs_trace.tracer().recorded > tr0
 
 
+#: One supervised check captured with a host lead-in before its first
+#: segment, traced into a run directory: argv run_dir, lead-in seconds.
+_LEAD_IN_RUN = """
+import sys, time
+from jepsen_tpu import resilience
+from jepsen_tpu.models import CASRegister
+from jepsen_tpu.obs import profiler, trace
+from jepsen_tpu.ops.encode import pack_with_init
+from jepsen_tpu.testing import simulate_register_history
+run_dir, lead_in = sys.argv[1], float(sys.argv[2])
+h = simulate_register_history(150, n_procs=5, n_vals=4, seed=3)
+p, kernel = pack_with_init(h, CASRegister())
+
+def check():
+    return resilience.supervised_check_packed(
+        p, kernel, capacity=64, expand=8, segment_iters=8)
+
+check()   # compile outside the capture
+resilience._inject_fault = (
+    lambda ctx: time.sleep(lead_in) if ctx["segment"] == 0 else None)
+trace.start_run(run_dir)
+profiler.attach(run_dir)
+assert check()["valid"] is True
+profiler.detach()
+trace.finish_run()
+"""
+
+
+class TestSharedClockMerge:
+    """The merge aligns on the capture's sync mark, so host work between
+    the capture's start and its first device operation cannot shift the
+    device records out of the host spans that launched them. The capture
+    runs in a process of its own: the profiler records every thread of
+    its process, and no other test's threads may add records."""
+
+    LEAD_IN_S = 0.06
+
+    @pytest.fixture(scope="class")
+    def run_dir(self, tmp_path_factory):
+        import subprocess
+        import sys
+        d = tmp_path_factory.mktemp("lead-in")
+        env = dict(os.environ, JTPU_PROF="1", JTPU_TRACE="1",
+                   JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
+        p = subprocess.run(
+            [sys.executable, "-c", _LEAD_IN_RUN, str(d),
+             str(self.LEAD_IN_S)], env=env, capture_output=True,
+            text=True, timeout=300)
+        assert p.returncode == 0, p.stderr[-3000:]
+        return str(d)
+
+    def _read(self, run_dir):
+        host, _ = obs_trace.read_trace(
+            os.path.join(run_dir, obs_trace.TRACE_NAME))
+        dev, _ = profiler.read_profile(run_dir)
+        assert dev, "no device-lane records extracted"
+        (cap,) = [s for s in host if s["name"] == profiler.CAPTURE_SPAN]
+        return host, dev, cap
+
+    def test_device_records_lie_inside_their_parent_span(self, run_dir):
+        host, dev, cap = self._read(run_dir)
+        assert all(r["capture"] == cap["sid"] for r in dev)
+        merged, _ = profiler.merged_records(run_dir)
+        merged = [m for m in merged if m.get("track") == "device"]
+        assert len(merged) == len(dev)
+        # every timed operation starts inside a device-call span, and
+        # one that also ends inside it is parented under it (the CPU
+        # client's pool threads may close an event after the host has
+        # its results, so an end can overhang a loaded host's span)
+        calls = [s for s in host if s["name"] in profiler.HOST_PARENTS]
+        ops = [m for m in merged if m["dur"] > 0]
+        assert ops
+        for m in ops:
+            (holder,) = [s for s in calls
+                         if s["ts"] <= m["ts"] <= s["ts"] + s["dur"]]
+            if m["ts"] + m["dur"] <= holder["ts"] + holder["dur"]:
+                assert m["pid"] == holder["sid"], m
+        # the first device op comes after the host lead-in
+        first = min(m["ts"] for m in merged)
+        assert first - cap["ts"] >= self.LEAD_IN_S * 1e9
+
+    def test_capture_without_sync_merges_by_legacy_rule(self, run_dir):
+        host, dev, cap = self._read(run_dir)
+        legacy = [{k: v for k, v in r.items()
+                   if k not in ("sync", "capture")} for r in dev]
+        merged = profiler.merge_into_host(host, legacy)
+        # the earliest device op lands on the capture span's start,
+        # ahead of the lead-in the host spent before it
+        assert min(m["ts"] for m in merged) == cap["ts"]
+        synced = profiler.merge_into_host(host, dev)
+        shift = min(m["ts"] for m in synced) - min(m["ts"]
+                                                   for m in merged)
+        assert shift >= self.LEAD_IN_S * 1e9
+
+
 # ---------------------------------------------------------------------------
 # Parsing + merging (synthetic captures: deterministic, platform-free)
 # ---------------------------------------------------------------------------
@@ -242,6 +337,36 @@ class TestParseMerge:
         doc = obs_trace.to_chrome(host + merged)
         assert all("name" in e and "ph" in e
                    for e in doc["traceEvents"])
+
+    def test_sync_mark_aligns_each_capture(self, tmp_path):
+        host_lane = [
+            {"ph": "M", "pid": 7, "name": "process_name",
+             "args": {"name": "/host:CPU"}},
+            {"ph": "M", "pid": 7, "tid": 2, "name": "thread_name",
+             "args": {"name": "python"}},
+            {"ph": "X", "pid": 7, "tid": 2, "ts": 20.0, "dur": 1.0,
+             "name": profiler.SYNC, "args": {"capture": "4"}},
+        ]
+        path = _write_capture(tmp_path, _TPU_EVENTS + host_lane)
+        recs, stats = profiler.parse_trace(path)
+        assert stats["sync"] == 20_000 and stats["device"] == 3
+        assert all(r["sync"] == 20_000 and r["capture"] == 4
+                   for r in recs)
+        host = [
+            {"name": profiler.CAPTURE_SPAN, "ts": 1_000_000,
+             "dur": 300_000, "tid": 5, "sid": 4, "sync_ts": 1_000_500},
+            {"name": "checker.segment", "ts": 1_070_000, "dur": 100_000,
+             "tid": 5, "sid": 6, "pid": 4},
+        ]
+        merged = profiler.merge_into_host(host, recs)
+        # profiler 20 us is host 1_000_500 ns: +980_500 ns for every op
+        assert [m["ts"] for m in merged] == [1_080_500, 1_090_500,
+                                             1_115_500]
+        assert {m["pid"] for m in merged} == {6}
+        # a record whose capture span is missing falls back to the
+        # legacy rule, onto the earliest capture span's start
+        orphan = [dict(recs[0], capture=99)]
+        assert profiler.merge_into_host(host, orphan)[0]["ts"] == 1_000_000
 
     def test_merge_empty_device_is_empty(self):
         assert profiler.merge_into_host([{"name": "x", "ts": 0,
