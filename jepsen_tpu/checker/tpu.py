@@ -114,8 +114,7 @@ def _suffix_min_inv(inv: np.ndarray, n: int) -> np.ndarray:
     """suffix_min[j] = min(inv[j:]), suffix_min[n] = RET_INF — lets the
     device test "any candidate beyond the window?" with one gather."""
     out = np.full(n + 1, int(RET_INF), dtype=np.int32)
-    for j in range(n - 1, -1, -1):
-        out[j] = min(int(inv[j]), int(out[j + 1]))
+    out[:n] = np.minimum.accumulate(np.asarray(inv[:n])[::-1])[::-1]
     return out
 
 
@@ -1123,11 +1122,21 @@ def _split_packed(p: PackedHistory, breq: int, cr: int,
     inv_req = pad(p.inv[:nr], breq, inf)
     # ro[j] = 1 iff required op j is read-only (see kernel.readonly) —
     # feeds the device search's greedy pure-op closure. Padding rows 0.
+    # The hook is a pure function of (f, v1, v2), so it is asked once
+    # per distinct triple: rows sorted by the triple form runs of equal
+    # triples (an exact grouping), one hook call answers each run.
     ro = np.zeros(breq, dtype=np.int32)
-    if kernel is not None and kernel.readonly is not None:
-        for j in range(nr):
-            if kernel.readonly(int(p.f[j]), int(p.v1[j]), int(p.v2[j])):
-                ro[j] = 1
+    if kernel is not None and kernel.readonly is not None and nr:
+        f, v1, v2 = p.f[:nr], p.v1[:nr], p.v2[:nr]
+        order = np.lexsort((v2, v1, f))
+        sf, s1, s2 = f[order], v1[order], v2[order]
+        starts = np.ones(nr, dtype=bool)
+        starts[1:] = (sf[1:] != sf[:-1]) | (s1[1:] != s1[:-1]) \
+            | (s2[1:] != s2[:-1])
+        flags = np.array([1 if kernel.readonly(int(sf[j]), int(s1[j]),
+                                               int(s2[j])) else 0
+                          for j in np.flatnonzero(starts)], dtype=np.int32)
+        ro[order] = flags[np.cumsum(starts) - 1]
     # sm: suffix-min of padded inv (padding is RET_INF, so entries <= nr
     # equal the required-only suffix-min — computed once, reused by fr)
     sm = _suffix_min_inv(inv_req, breq)

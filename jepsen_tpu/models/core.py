@@ -356,6 +356,9 @@ class KernelSpec:
     #: NEVER change the state at any state where it succeeds (register
     #: read, cas(x,x), set read). Drives the checkers' greedy pure-op
     #: closure (partial-order reduction); None disables the reduction.
+    #: Must be a pure function of the triple: the packer asks it once
+    #: per distinct (f_code, v1, v2) and reuses the answer for every op
+    #: that carries the same triple.
     readonly: Optional[Callable] = None
     #: Human rendering of a packed state word for counterexample reports:
     #: (state, value_table) -> str. None falls back to the raw integer.

@@ -126,6 +126,8 @@ def _op_values(f_code: int, f: Any, inv_value: Any, ok_value: Any,
 
     cas carries (old, new); reads use the *completion* value (knossos
     complete-fills reads); writes use the invocation value.
+    pack_history inlines this split for ok rows; StreamPacker calls it,
+    and tests/test_pack_columnar.py holds the two packers equal.
     """
     if f == "cas":
         v = inv_value
@@ -145,85 +147,136 @@ def pack_history(history: Sequence[Op], kernel: KernelSpec,
 
     Steps: (1) walk events assigning event indices; (2) pair invocations with
     completions per process; (3) drop failed pairs and crashed reads (a
-    crashed read constrains nothing); (4) intern values; (5) sort ops by
+    crashed read constrains nothing); (4) intern values; (5) order ops by
     return index (RET_INF last, tie-broken by invocation index);
     (6) kernel remap (e.g. the queue kernel's value-slot interval
     coloring) and capacity validation — either may raise ValueError, on
     which the caller falls back to the generic object search.
+
+    One walk appends the ok rows straight into int columns: an ok row's
+    return index is its completion event, so arrival order IS return
+    order and only the crashed section (RET_INF) needs a sort, by
+    invocation index. The default (register) value split and the
+    interner's int lookup are inlined — this walk is the whole host
+    cost of packing a long history.
     """
     intern = intern or _Interner()
-    if kernel.encode_op is not None:
+    f_codes = kernel.f_codes
+    drop_crashed = kernel.drop_crashed
+    encode_op = kernel.encode_op
+    intern_id = intern.id
+    table = intern.table
+    nil = int(NIL_ID)
+    if encode_op is not None:
         def encode(fc, f, inv_value, ok_value):
-            return kernel.encode_op(fc, f, inv_value, ok_value, intern.id)
+            return encode_op(fc, f, inv_value, ok_value, intern_id)
     else:
         def encode(fc, f, inv_value, ok_value):
             return _op_values(fc, f, inv_value, ok_value, intern)
+
     pending: Dict[Any, Tuple[int, Op]] = {}
-    rows = []  # (inv_idx, ret_idx, f, v1, v2, process, inv_op, comp_op)
+    inv_c: List[int] = []
+    ret_c: List[int] = []
+    f_c: List[int] = []
+    v1_c: List[int] = []
+    v2_c: List[int] = []
+    proc_raw: list = []
+    ops: list = []
+    crashed = []  # (inv_idx, f, v1, v2, process, inv_op, comp_op)
 
     for ev, o in enumerate(history):
-        if o.is_invoke:
+        typ = o.type
+        if typ == "invoke":
             pending[o.process] = (ev, o)
-        elif o.process in pending:
-            inv_ev, inv_op = pending.pop(o.process)
-            if o.is_fail:
-                continue  # known not to have happened
-            fc = kernel.f_codes.get(inv_op.f)
-            if fc is None:
-                raise ValueError(
-                    f"op f={inv_op.f!r} not supported by model "
-                    f"{kernel.name!r} (codes: {sorted(kernel.f_codes)})")
-            if o.is_info:
-                if fc == F_READ or (
-                        kernel.drop_crashed is not None
-                        and kernel.drop_crashed(fc, inv_op.value)):
-                    # crashed read — or a crashed op the reference
-                    # semantics can never linearize (e.g. a nil-value
-                    # dequeue) — constrains nothing
-                    continue
-                v1, v2 = encode(fc, inv_op.f, inv_op.value, None)
-                rows.append((inv_ev, int(RET_INF), fc, v1, v2,
-                             inv_op.process, inv_op, o))
-            else:  # ok
-                v1, v2 = encode(fc, inv_op.f, inv_op.value, o.value)
-                rows.append((inv_ev, ev, fc, v1, v2, inv_op.process,
-                             inv_op, o))
+            continue
+        entry = pending.pop(o.process, None)
+        if entry is None or typ == "fail":
+            continue  # unpaired, or known not to have happened
+        inv_ev, inv_op = entry
+        f = inv_op.f
+        fc = f_codes.get(f)
+        if fc is None:
+            raise ValueError(
+                f"op f={f!r} not supported by model {kernel.name!r} "
+                f"(codes: {sorted(f_codes)})")
+        inv_value = inv_op.value
+        if typ == "info":
+            if fc == F_READ or (drop_crashed is not None
+                                and drop_crashed(fc, inv_value)):
+                # crashed read — or a crashed op the reference
+                # semantics can never linearize (e.g. a nil-value
+                # dequeue) — constrains nothing
+                continue
+            v1, v2 = encode(fc, f, inv_value, None)
+            crashed.append((inv_ev, fc, v1, v2, inv_op.process, inv_op, o))
+            continue
+        # ok: _op_values' default split, inlined with the int lookup
+        if encode_op is not None:
+            v1, v2 = encode(fc, f, inv_value, o.value)
+        elif f == "cas":
+            if inv_value is None:
+                v1 = v2 = nil
+            else:
+                old, new = inv_value
+                v1 = table.get(old) if type(old) is int else None
+                if v1 is None:
+                    v1 = intern_id(old)
+                v2 = table.get(new) if type(new) is int else None
+                if v2 is None:
+                    v2 = intern_id(new)
+        else:
+            if fc == F_READ or f == "read":
+                val = o.value
+                if val is None:
+                    val = inv_value
+            else:
+                val = inv_value
+            v1 = table.get(val) if type(val) is int else None
+            if v1 is None:
+                v1 = intern_id(val)
+            v2 = nil
+        inv_c.append(inv_ev)
+        ret_c.append(ev)
+        f_c.append(fc)
+        v1_c.append(v1)
+        v2_c.append(v2)
+        proc_raw.append(inv_op.process)
+        ops.append((inv_op, o))
     # invocations with no completion at all == crashed (same as info)
     for inv_ev, inv_op in pending.values():
-        fc = kernel.f_codes.get(inv_op.f)
+        fc = f_codes.get(inv_op.f)
         if fc is None or fc == F_READ or (
-                kernel.drop_crashed is not None
-                and kernel.drop_crashed(fc, inv_op.value)):
+                drop_crashed is not None
+                and drop_crashed(fc, inv_op.value)):
             continue
         v1, v2 = encode(fc, inv_op.f, inv_op.value, None)
-        rows.append((inv_ev, int(RET_INF), fc, v1, v2, inv_op.process,
-                     inv_op, None))
+        crashed.append((inv_ev, fc, v1, v2, inv_op.process, inv_op, None))
 
-    # sort by (ret, inv)
-    rows.sort(key=lambda r: (r[1], r[0]))
-    n = len(rows)
-    n_required = sum(1 for r in rows if r[1] != int(RET_INF))
+    n_required = len(inv_c)
+    crashed.sort(key=lambda r: r[0])  # invocation indices are distinct
+    for inv_ev, fc, v1, v2, prc, inv_op, comp in crashed:
+        inv_c.append(inv_ev)
+        f_c.append(fc)
+        v1_c.append(v1)
+        v2_c.append(v2)
+        proc_raw.append(prc)
+        ops.append((inv_op, comp))
+    ret_c.extend([int(RET_INF)] * len(crashed))
+    # dense process ids in row order: required rows, then crashed rows
+    procs: Dict[Any, int] = {}
+    proc_c = [procs.setdefault(p, len(procs)) for p in proc_raw]
 
-    def col(i, dtype=np.int32):
-        return np.asarray([r[i] for r in rows], dtype=dtype)
-
-    procs = {}
-    proc_col = []
-    for r in rows:
-        p = r[5]
-        if p not in procs:
-            procs[p] = len(procs)
-        proc_col.append(procs[p])
+    def col(c):
+        return np.array(c, dtype=np.int32)
 
     packed = PackedHistory(
-        f=col(2), v1=col(3), v2=col(4), inv=col(0), ret=col(1),
-        process=np.asarray(proc_col, dtype=np.int32) if n else
-        np.zeros(0, np.int32),
+        f=col(f_c), v1=col(v1_c), v2=col(v2_c), inv=col(inv_c),
+        ret=col(ret_c), process=col(proc_c),
         n_required=n_required,
         init_state=(kernel.init_state if init_state is None
                     else init_state),
         value_table=intern.values,
-        ops=[(r[6], r[7]) for r in rows],
+        ops=ops,
     )
     if kernel.remap is not None:
         kernel.remap(packed)     # raises ValueError when it cannot fit
