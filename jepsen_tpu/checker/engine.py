@@ -231,9 +231,19 @@ class Engine:
 
     def jit_batch(self, kernel_id: int, capacity: int, window: int,
                   expand: Optional[int] = None, unroll: int = 1,
-                  tiebreak: str = "lex"):
-        """The vmapped keyed-batch executable — body identical to
-        ``_jit_batch``."""
+                  tiebreak: str = "lex", mesh=None,
+                  axis: Optional[str] = None):
+        """The vmapped keyed-batch executable.
+
+        With a ``mesh``, the vmapped search runs under ``jax.shard_map``
+        over ``axis``: every input and output is split on its leading
+        (key) dimension, and each device runs its own while-loop over
+        its own keys, stopping at its own slowest key. No collective
+        runs inside a level; the verdict vectors come back as one array
+        sharded over the mesh. The search's loop carry starts from
+        constants, which the replication check types as equal on every
+        device, and leaves the body differing per device; the check
+        refuses such a loop, so it is off for this one function."""
         import jax
         kernel = T._KERNELS_BY_ID[kernel_id]
 
@@ -247,10 +257,18 @@ class Engine:
                     f, v1, v2, ro, fr, inv, ret, sm, cf, cv1, cv2, cinv,
                     cps, nr, ini)
 
-            return jax.jit(batched)
+            if mesh is None:
+                return jax.jit(batched)
+            from jax.sharding import PartitionSpec as P
+            return jax.jit(jax.shard_map(
+                batched, mesh=mesh, in_specs=P(axis), out_specs=P(axis),
+                check_vma=False))
 
-        return self._get(("batch", kernel_id, capacity, window, expand,
-                          unroll, tiebreak), build)
+        key = ("batch", kernel_id, capacity, window, expand, unroll,
+               tiebreak)
+        if mesh is not None:
+            key += (tuple(int(d.id) for d in mesh.devices.flat), axis)
+        return self._get(key, build)
 
     def jit_batch_segment(self, kernel_id: int, capacity: int,
                           window: int, expand: Optional[int] = None,

@@ -51,7 +51,7 @@ admission-control daemon of ROADMAP item 1).
 from __future__ import annotations
 
 import os
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -523,7 +523,16 @@ def trace_candidate(cand: Candidate, kernel, cost: bool = False,
     Returns ``{"ok", "error", "cost"}``; memoized per bucket. A sharded
     candidate needs a real mesh to trace (with_sharding_constraint); when
     none is supplied the result is ``ok=None`` (untraceable here, not
-    broken)."""
+    broken).
+
+    Without ``cost``, a ``single`` candidate reads its ``segment``
+    twin's trace: both executables wrap one ``_search_fn`` body (carry
+    set-up, level step, loop), and the segment's trace runs all of it
+    plus the checkpoint carry's layout, so tracing the body twice finds
+    nothing new."""
+    if cand.kind == "single" and not cost:
+        return trace_candidate(replace(cand, kind="segment"), kernel,
+                               mesh=mesh)
     key = (T._kernel_key(kernel), cand.kind, cand.capacity, cand.window,
            cand.expand, cand.unroll, cand.breq, cand.crw, cand.keys,
            cand.tiebreak, cand.mesh_axis, bool(cost))

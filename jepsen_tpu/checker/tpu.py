@@ -715,6 +715,12 @@ _DEVICE_SECONDS = obs_metrics.histogram(
 _LEVELS_TOTAL = obs_metrics.counter(
     "jtpu_search_levels_total",
     "search levels executed on device (per-call/per-segment deltas)")
+_CHIP_LEVELS = obs_metrics.counter(
+    "jtpu_keyed_chip_levels_total",
+    "levels each chip's loop ran in keyed batch launches: per launch, "
+    "the chip's own slowest key, labeled by the chip's place on the "
+    "keys axis (0 off a mesh); jtpu_search_levels_total times the "
+    "chips over it is the level skew between chips")
 _SEGMENTS_TOTAL = obs_metrics.counter(
     "jtpu_search_segments_total", "checkpointed device segments run")
 _FRONTIER_HWM = obs_metrics.gauge(
@@ -1079,9 +1085,10 @@ def _segment_config(segment_iters: Optional[int]) -> Optional[int]:
 
 def _jit_batch(kernel_id: int, capacity: int, window: int,
                expand: Optional[int] = None, unroll: int = 1,
-               tiebreak: str = "lex"):
+               tiebreak: str = "lex", mesh=None,
+               axis: Optional[str] = None):
     return _engine().jit_batch(kernel_id, capacity, window, expand,
-                               unroll, tiebreak)
+                               unroll, tiebreak, mesh, axis)
 
 
 def _jit_batch_segment(kernel_id: int, capacity: int, window: int,
@@ -2272,8 +2279,11 @@ def check_keyed_tpu(keyed: Dict[Any, Sequence], model: Model,
     independent.clj:246-296 fans the checker out per key; here the fan-out
     is a vmapped, mesh-sharded tensor program).
 
-    With a mesh, key-batch arrays are sharded over ``axis`` and XLA's SPMD
-    partitioner runs each shard's searches on its own device over ICI.
+    With a mesh, each crash-width cohort is split over ``axis`` as
+    evenly as it goes, each device's share padded to a multiple of
+    ``_MESH_KEY_STEP`` keys, and each device runs its own while-loop
+    over its own keys (``jax.shard_map``): no collective runs inside a
+    level, and the verdict vectors are gathered once a launch ends.
     capacity=None escalates the whole batch through the narrow capacity
     ladder plus WIDE_LADDER tail rungs, re-running only keys whose
     searches overflowed (and only on rungs that actually grow their
@@ -2439,7 +2449,7 @@ def _check_keyed(keyed, model, kernel, ops, capacity, window, mesh, axis,
     devices: set = set()
     try:
         results = _keyed_ladder(
-            ladder, rows, adaptive, tb_env, mesh, axis, packed, breq,
+            ladder, rows, adaptive, tb_env, mesh, axis, packed,
             kernel, results, devices)
     finally:
         _prof.__exit__(None, None, None)
@@ -2459,11 +2469,61 @@ def _check_keyed(keyed, model, kernel, ops, capacity, window, mesh, axis,
     return out
 
 
+#: On a keys mesh each chip's share of a cohort is padded up to a
+#: multiple of this many keys. A cohort's size moves with how many of a
+#: test run's keys crashed, and every size is an executable of its own
+#: (on a v5e-4 host, 136 keys of 300 ops: ~9 s to its first call cold,
+#: ~4.9 s warm from the persistent cache); steps of 4 keys a chip put a
+#: run's cohorts on a few sizes for a few padded rows.
+_MESH_KEY_STEP = 4
+
+
+def _mesh_rows(n: int, chips: int):
+    """``n`` keys split over ``chips`` as evenly as they go, each chip's
+    share padded up to a multiple of ``_MESH_KEY_STEP`` rows. Returns
+    key ``j``'s row and the padded batch's row count."""
+    per = -(-n // chips)
+    per += (-per) % _MESH_KEY_STEP
+    base, extra = divmod(n, chips)
+    rows = np.concatenate([c * per + np.arange(base + (c < extra))
+                           for c in range(chips)])
+    return rows, per * chips
+
+
+def _place_keys(arrays, rows, size, mesh, axis):
+    """Lay a cohort's column arrays out at ``rows`` of a ``size``-row
+    batch and place it split over the mesh axis. Returns
+    ``(arrays, multiproc)``."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    # The other rows are trivially complete (n_required=0 finishes at
+    # level 0) — repeating a real key would re-run its search, possibly
+    # the batch's most expensive, once per padded row.
+    def _pad_col(a, c):
+        out = np.repeat(a[-1:], size, axis=0)
+        if c == "nr":
+            out[:] = 0
+        out[rows] = a
+        return out
+    arrays = [_pad_col(a, c) for a, c in zip(arrays, _COLS)]
+    sh_row = NamedSharding(mesh, P(axis))
+    if jax.process_count() > 1:
+        # Multi-host (DCN) mesh: device_put cannot address other hosts'
+        # devices. Every process holds the SAME global batch (the keyed
+        # dict is control-plane data), so each builds the global array
+        # from its addressable slices.
+        return [jax.make_array_from_callback(a.shape, sh_row,
+                                             lambda idx, a=a: a[idx])
+                for a in arrays], True
+    return [jax.device_put(a, sh_row) for a in arrays], False
+
+
 def _keyed_ladder(ladder, rows, adaptive, tb_env, mesh, axis, packed,
-                  breq, kernel, results, devices):
+                  kernel, results, devices):
     """The keyed batch's escalation loop (split out so the profiler
     capture wraps exactly the device work). Adds the ids of the devices
     each launch's outputs lived on to ``devices``."""
+    chips = 1 if mesh is None else int(mesh.shape[axis])
     for step, (cap, win, exp) in enumerate(ladder):
         if not rows:
             break
@@ -2501,56 +2561,20 @@ def _keyed_ladder(ladder, rows, adaptive, tb_env, mesh, axis, packed,
         # Sub-batch per crashed-section width: crash-free keys must not
         # pay the crash grids + dominance passes sized for the batch's
         # crashiest key (a distinct compilation per width regardless).
-        # On a mesh, cohorting would serialize one data-parallel launch
-        # into per-width launches each padded up to the axis — a net
-        # loss whenever key count is near device count — so the sharded
-        # path keeps the single widest-width batch.
+        # On a mesh each cohort is split over the axis (_mesh_rows).
         by_cr: Dict[int, list] = {}
-        if mesh is None:
-            for r in runnable:
-                by_cr.setdefault(r[6], []).append(r)
-        else:
-            wmax = max(r[6] for r in runnable)
-            by_cr[wmax] = [
-                r if r[6] == wmax else
-                (r[0], _split_packed(packed[r[0]], breq, wmax, kernel),
-                 r[2], r[3], r[4], r[5], wmax, r[7])
-                for r in runnable]
+        for r in runnable:
+            by_cr.setdefault(r[6], []).append(r)
         for crw, grp in sorted(by_cr.items()):
             arrays = [np.stack([r[1][c] for r in grp]) for c in _COLS]
             multiproc = False
+            at = range(len(grp))    # each key's row of the launch
             if mesh is not None:
-                from jax.sharding import NamedSharding, PartitionSpec as P
-                # Pad the key batch up to the mesh axis size so it
-                # divides.
-                per = mesh.shape[axis]
-                pad = (-len(grp)) % per
-                if pad:
-                    # Pad with trivially-complete rows (n_required=0
-                    # finishes at level 0) — repeating a real key would
-                    # re-run its search, possibly the batch's most
-                    # expensive, pad times.
-                    def _pad_col(a, c):
-                        fill = np.repeat(a[-1:], pad, axis=0)
-                        if c == "nr":
-                            fill = np.zeros_like(fill)
-                        return np.concatenate([a, fill])
-                    arrays = [_pad_col(a, c)
-                              for a, c in zip(arrays, _COLS)]
-                sh_row = NamedSharding(mesh, P(axis))
-                multiproc = jax.process_count() > 1
-                if multiproc:
-                    # Multi-host (DCN) mesh: device_put cannot address
-                    # other hosts' devices. Every process holds the SAME
-                    # global batch (the keyed dict is control-plane
-                    # data), so each builds the global array from its
-                    # addressable slices.
-                    arrays = [jax.make_array_from_callback(
-                                  a.shape, sh_row,
-                                  lambda idx, a=a: a[idx])
-                              for a in arrays]
-                else:
-                    arrays = [jax.device_put(a, sh_row) for a in arrays]
+                at, size = _mesh_rows(len(grp), chips)
+                with obs.span("checker.place", keys=len(grp), chips=chips,
+                              pad=size - len(grp)):
+                    arrays, multiproc = _place_keys(arrays, at, size, mesh,
+                                                    axis)
             # The slim entry rung runs the high-forced-fraction cohort
             # (staggered keys), whose levels are fast-forward loops, not
             # sorts — unrolling 2 search steps per while_loop iteration
@@ -2562,9 +2586,9 @@ def _keyed_ladder(ladder, rows, adaptive, tb_env, mesh, axis, packed,
             unroll = _unroll_factor(2 if adaptive and step == 0
                                     else _UNROLL)
             fn = _jit_batch(_kernel_key(kernel), cap, win, exp,
-                            unroll, tiebreak=tb)
+                            unroll, tiebreak=tb, mesh=mesh, axis=axis)
             shape_key = ("batch", _kernel_key(kernel), cap, win, exp,
-                         unroll, tb, tuple(arrays[0].shape), crw)
+                         unroll, tb, tuple(arrays[0].shape), crw, chips)
             _TRANSFER_BYTES.inc(
                 sum(int(getattr(a, "nbytes", 0)) for a in arrays),
                 direction="host-to-device")
@@ -2588,8 +2612,12 @@ def _keyed_ladder(ladder, rows, adaptive, tb_env, mesh, axis, packed,
                 done, lossy, wovf, best, levels = (np.asarray(x)
                                                    for x in scalars)
             # a vmapped batch advances every key per program level, so
-            # the device executed the slowest key's level count
-            _LEVELS_TOTAL.inc(int(levels.max(initial=0)))
+            # each chip executed its own slowest key's level count, and
+            # the launch lasted as long as the slowest chip's loop
+            chip_levels = levels.reshape(chips, -1).max(axis=1)
+            for i, n in enumerate(chip_levels):
+                _CHIP_LEVELS.inc(int(n), chip=str(i))
+            _LEVELS_TOTAL.inc(int(chip_levels.max()))
             # Pool columns ([capacity] rows per key) are only read for
             # clean refutations — don't ship up to 16384 ints/key
             # off-device (and over DCN) for the common all-valid rung.
@@ -2608,7 +2636,7 @@ def _keyed_ladder(ladder, rows, adaptive, tb_env, mesh, axis, packed,
                             for x in pools)
                     pk, ps, pa = (np.asarray(x) for x in pools)
             for r, (key, cols, wneed, mcap, mwin, ffrac, _, work) in \
-                    enumerate(grp):
+                    zip(at, grp):
                 res = _result(bool(done[r]), bool(lossy[r]),
                               bool(wovf[r]), int(best[r]),
                               int(levels[r]), packed[key],

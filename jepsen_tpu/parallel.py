@@ -5,18 +5,22 @@ jepsen/src/jepsen/control.clj) plus JVM threads (workers,
 core.clj:219-265). This rebuild keeps the SSH control plane
 (jepsen_tpu.control) and adds a second, accelerator-native axis the
 reference never had: histories bit-packed to integer columns and
-checked as ONE sharded tensor program over a `jax.sharding.Mesh`
-(checker/tpu.py::check_keyed_tpu), with XLA inserting the collectives.
+checked as ONE tensor program split over a `jax.sharding.Mesh`
+(checker/tpu.py::check_keyed_tpu).
 
-The design follows the standard TPU scaling recipe: pick a mesh,
-annotate shardings (`NamedSharding(mesh, P("keys"))` over the
-independent-key axis — P-compositional checking is embarrassingly
-data-parallel, so no cross-device collectives are needed in the hot
-loop and ICI/DCN only carries the final validity reduction), and let
-the compiler do the rest. Multi-host: every process contributes its
-local devices via `jax.distributed.initialize`; the same jitted program
-runs SPMD on each host (certified by the two-process DCN dryrun,
-__graft_entry__.dryrun_dcn).
+P-compositional checking is embarrassingly data-parallel over the
+independent-key axis, and the program says so: each crash-width cohort
+of keys is split over the mesh's ``keys`` axis as evenly as it goes,
+each device's share padded with trivially complete rows to a multiple
+of 4 keys (so cohorts fall on few executable shapes), and placed with
+`NamedSharding(mesh, P("keys"))`, and the vmapped search runs under
+`jax.shard_map`, so each device runs its own while-loop over its own
+keys and stops at its own slowest key. No collective runs inside a
+level; the per-key verdict vectors come back to the host once a launch
+ends (gathered across processes on a multi-host mesh). Multi-host:
+every process contributes its local devices via
+`jax.distributed.initialize`; the same program runs SPMD on each host
+(certified by the two-process DCN dryrun, __graft_entry__.dryrun_dcn).
 
 A second, orthogonal axis exists for single searches: pool sharding
 (`checker.tpu.check_packed_sharded`) partitions ONE search's frontier

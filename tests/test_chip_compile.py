@@ -3,7 +3,9 @@
 Compiles for ONE chip of a described (not attached) TPU v5e topology:
 the single-key search at the 10k-op headline shape on the two rungs the
 headline climbs through, the segmented step the supervised default path
-runs, and the vmapped keyed batch at the 50 keys x 200 ops config. No
+runs, and the vmapped keyed batch at the 50 keys x 200 ops config; and
+for all four chips of the host, the keys-mesh batch at the four-chip
+cell's shape (136 keys of 300 ops), with no collective in it. No
 chip time, nothing runs: a compile that passes is not a chip run. The
 (4096, 256) rung (~40 s) and the top rung stay out.
 
@@ -22,14 +24,13 @@ V5E_HBM_BYTES = 16 * 1024**3
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     import jax
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        desc = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — any failure means "can't here"
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
@@ -38,9 +39,15 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _abstract(tree, sharding):
@@ -125,3 +132,36 @@ def test_keyed_batch_compiles_at_config_shape(one_chip):
         T._kernel_key(kernel), cap, T.WINDOW, exp, 2, tiebreak="hash")
     args = _abstract([cols[c] for c in T._COLS], one_chip)
     _fits(fn.lower(*args).compile())
+
+
+def test_keyed_mesh_batch_compiles_for_four_chips(topo):
+    # the four-chip cell's crash-free cohort (84-95 of its 136 keys of
+    # 300 ops, laid out as 24 rows a chip) on the slim entry rung: each
+    # chip's own loop, so the chip's compiler puts no collective anywhere
+    # in the program
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from jepsen_tpu.checker import tpu as T
+    from jepsen_tpu.checker.engine import Engine
+    from jepsen_tpu.models import CASRegister
+    from jepsen_tpu.models.core import kernel_spec_for
+    mesh = Mesh(np.array(topo.devices), ("keys",))
+    kernel = kernel_spec_for(CASRegister())
+    cap, exp = T.CAPACITY_LADDER[0]
+    fn = Engine("chip-compile").jit_batch(
+        T._kernel_key(kernel), cap, T.WINDOW, exp, 2, tiebreak="hash",
+        mesh=mesh, axis="keys")
+    keys, breq = 96, T._bucket(300)
+    shapes = {c: (keys, breq) for c in T._COLS}
+    shapes.update(sm=(keys, breq + 1), nr=(keys,), ini=(keys,),
+                  **{c: (keys, 0) for c in ("cf", "cv1", "cv2", "cinv",
+                                            "cps")})
+    sh = NamedSharding(mesh, P("keys"))
+    args = [jax.ShapeDtypeStruct(shapes[c], np.int32, sharding=sh)
+            for c in T._COLS]
+    compiled = fn.lower(*args).compile()
+    _fits(compiled)
+    hlo = compiled.as_text()
+    for op in ("all-reduce", "all-gather", "collective-permute",
+               "all-to-all", "reduce-scatter"):
+        assert op not in hlo, op
