@@ -44,6 +44,11 @@ HIST-OPEN-INVOKE            note      invoke still open at history end
 
 Only *error*-severity findings gate; notes surface legal-but-noteworthy
 structure (crashed ops are jepsen semantics, not damage).
+
+The device entry points need only to know whether the gate raises, so
+they go through :func:`require_well_formed`: :func:`screen_history`, one
+tight walk over ``Op`` slots that proves no error rule can fire, and
+the full linter only when the screen is in doubt.
 """
 
 from __future__ import annotations
@@ -54,6 +59,7 @@ from typing import Any, Iterable, List, Optional
 from jepsen_tpu.analysis import ERROR, Finding, NOTE, WARNING, relpath
 from jepsen_tpu.analysis.opcheck import (INVALID_TYPE_FLAG,
                                          invalid_op_type)
+from jepsen_tpu.history import Op
 
 #: The nemesis pseudo-process: its ops are all ``info`` and never pair
 #: as invoke/complete (core.clj:292), so pairing rules exempt it.
@@ -220,6 +226,97 @@ def gate_history(history: Iterable[Any], where: str = "device search",
     if errs:
         raise MalformedHistoryError(errs, where=where)
     return findings
+
+
+def screen_history(history: Any) -> bool:
+    """True only when no error-severity rule of :func:`lint_history`
+    can fire on ``history``; False at the first doubt.
+
+    One walk over the ``Op`` slots, keeping only each open invocation's
+    ``f``. It proves what the error rules check: legal types with no
+    ``INVALID_TYPE_FLAG``, strictly increasing assigned indexes, no
+    invoke on a process with an open invocation, no ok/fail without
+    one, no completion whose ``f`` differs from its invocation's.
+    Nemesis ops are checked for type and index and never pair. Notes
+    and warnings (crashed ops, bare infos, an invoke with no ``f``,
+    decode errors) never gate, so they pass. Doubt covers a row that is
+    not an ``Op``, an ``extra`` that is not a dict, an index that is not
+    an ``int``, any exception, and an input that is not a list or tuple
+    (a one-shot iterable must be walked once, by the linter).
+    """
+    if not isinstance(history, (list, tuple)):
+        return False
+    # A nemesis op enters ``open_f`` like any other, and every branch
+    # that could refuse lets it through: no other process shares its
+    # key, so it never pairs, and the common path compares no process
+    # with NEMESIS.
+    open_f: dict = {}
+    pop = open_f.pop
+    last = -1
+    # Op.index before History.index() assigns one; the identity test
+    # is a shortcut, and any other -1 passes the checks below too
+    unset = -1
+    op_cls = Op
+    flag = INVALID_TYPE_FLAG
+    nemesis = NEMESIS
+    try:
+        for o in history:
+            if type(o) is not op_cls:
+                return False
+            extra = o.extra
+            if extra is not None and (type(extra) is not dict
+                                      or extra.get(flag)):
+                return False
+            idx = o.index
+            if idx is not unset:
+                if type(idx) is not int:
+                    return False
+                if idx > last:
+                    last = idx
+                elif idx >= 0:
+                    return False
+            typ = o.type
+            proc = o.process
+            if typ == "invoke":
+                if proc in open_f and proc != nemesis:
+                    return False
+                open_f[proc] = o.f
+            elif typ == "ok" or typ == "fail" or typ == "info":
+                pf = pop(proc, pop)
+                if pf is pop:
+                    if typ != "info" and proc != nemesis:
+                        return False
+                else:
+                    f = o.f
+                    if f is not None and pf is not None and pf != f \
+                            and proc != nemesis:
+                        return False
+            else:
+                return False
+    except Exception:
+        # doubt: the linter walks it and raises whatever it raises
+        return False
+    return True
+
+
+def require_well_formed(history: Any, where: str = "device search") -> str:
+    """The device paths' gate: raise exactly what :func:`gate_history`
+    raises, walking the full linter only when :func:`screen_history`
+    is in doubt. Returns the path taken, ``"screen"`` or ``"lint"``
+    (counted in ``jtpu_history_gate_total{path}``), or ``"off"`` under
+    the kill switch, where neither walk runs."""
+    if not gate_enabled():
+        return "off"
+    from jepsen_tpu.obs import metrics as obs_metrics
+    path = "screen" if screen_history(history) else "lint"
+    obs_metrics.counter(
+        "jtpu_history_gate_total",
+        "device-path history gate calls, labeled path=screen (the "
+        "screen proved the history well formed) or path=lint (the full "
+        "linter walked it)").inc(path=path)
+    if path == "lint":
+        gate_history(history, where=where)
+    return path
 
 
 def lint_history_file(path: str, root: Optional[str] = None

@@ -2210,9 +2210,9 @@ def _lint_and_pack(history: History, model: Model, where: str):
     """The single-history preamble: the mandatory history gate, then
     the packed encoding. ``(packed, kernel)``, or None when the model
     has no integer kernel or an op is not encodable."""
-    from jepsen_tpu.analysis.history_lint import gate_history
-    with obs.span("checker.lint"):
-        gate_history(history, where=where)
+    from jepsen_tpu.analysis.history_lint import require_well_formed
+    with obs.span("checker.lint") as sp:
+        sp.set(path=require_well_formed(history, where=where))
     with obs.span("checker.pack"):
         try:
             return pack_with_init(history, model)
@@ -2313,23 +2313,27 @@ def _check_keyed(keyed, model, kernel, ops, capacity, window, mesh, axis,
     packed: Dict[Any, PackedHistory] = {}
     from jepsen_tpu.analysis import summarize
     from jepsen_tpu.analysis.history_lint import (MalformedHistoryError,
-                                                  gate_history)
+                                                  require_well_formed)
     # Per-key pre-search gate: a malformed key goes UNKNOWN with rule
     # ids (the batch must not abort, matching the per-key encode-failure
     # contract below), and never reaches the packed encoder or a
     # compilation.
     linted = []
-    with obs.span("checker.lint", keys=len(keys), ops=ops):
+    with obs.span("checker.lint", keys=len(keys), ops=ops) as sp:
+        paths = set()
         for k in keys:
             try:
-                gate_history(keyed[k], where=f"the keyed device search "
-                                             f"(key {k!r})")
+                paths.add(require_well_formed(
+                    keyed[k], where=f"the keyed device search (key {k!r})"))
             except MalformedHistoryError as e:
+                paths.add("lint")
                 results[k] = {"valid": UNKNOWN, "backend": "tpu",
                               "error": str(e),
                               "lint": summarize(e.findings)}
             else:
                 linted.append(k)
+        # without a "lint", every key took the same path
+        sp.set(path="lint" if "lint" in paths else paths.pop())
     with obs.span("checker.pack", keys=len(linted)):
         for k in linted:
             try:

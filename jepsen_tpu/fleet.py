@@ -1507,9 +1507,9 @@ def check_history_fleet(history, model, hosts: Any = None,
     """Pack + fleet check (mirrors check_history_tpu's contract: the
     mandatory history gate first, None when the model has no integer
     kernel)."""
-    from jepsen_tpu.analysis.history_lint import gate_history
+    from jepsen_tpu.analysis.history_lint import require_well_formed
     from jepsen_tpu.ops.encode import pack_with_init
-    gate_history(history, where="the elastic fleet search")
+    require_well_formed(history, where="the elastic fleet search")
     try:
         pk = pack_with_init(history, model)
     except ValueError:
