@@ -217,7 +217,9 @@ def phase_cli(workdir: str, seconds: int = 3) -> None:
 
 
 def keyed_histories(n_keys: int, n_ops: int):
-    """The hazelcast-style multi-key CAS config (bench.py's config 5)."""
+    """The hazelcast-style multi-key CAS config: ``n_keys`` CAS
+    registers of ``n_ops`` ops each, 5 processes, 8 values, crash
+    probability 0.002."""
     from jepsen_tpu.testing import simulate_register_history
     return {k: simulate_register_history(n_ops, n_procs=5, n_vals=8,
                                          seed=1000 + k, crash_p=0.002)
@@ -312,8 +314,8 @@ def phase_served(root: str, n_ops: int = 10_000, keyed_ops: int = 200,
 
 def phase_keyed_mesh(n_keys: int = 256, n_ops: int = 2000,
                      n_devices: int = 4) -> None:
-    """The keyed batch at the TPU bench shape (bench.py's dense keyed
-    cell) on one chip and on an ``n_devices`` mesh."""
+    """The keyed batch (``keyed_histories``: 256 keys of 2,000 ops by
+    default) on one chip and on an ``n_devices`` mesh."""
     from jepsen_tpu import parallel
     from jepsen_tpu.checker.native import check_keyed_native
     from jepsen_tpu.checker.tpu import check_keyed_tpu

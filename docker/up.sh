@@ -26,7 +26,7 @@ if [ ! -f ./secret/node.env ]; then
 fi
 
 # The control image needs the framework source in its build context.
-rm -rf control/jepsen_tpu control/tests control/bench.py
-cp -r ../jepsen_tpu ../tests ../bench.py control/ 2>/dev/null || true
+rm -rf control/jepsen_tpu control/tests
+cp -r ../jepsen_tpu ../tests control/ 2>/dev/null || true
 
 exec docker compose up --build "$@"

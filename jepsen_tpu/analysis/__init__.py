@@ -21,7 +21,7 @@ that front end, done statically. Four passes:
 3. :mod:`~jepsen_tpu.analysis.jax_lint` — AST pass over
    ``checker/*.py`` and ``ops/encode.py`` for jit-unsafe patterns: host
    syncs inside traced bodies, unhashable arguments defeating the
-   ``_jit_single``/``_jit_segment``/``_jit_batch`` caches, bit-width
+   ``_jit_segment``/``_jit_batch`` caches, bit-width
    overflow in the packed op encoding.
 4. :mod:`~jepsen_tpu.analysis.lockset_lint` — a static race detector
    for the threaded stack: the legacy dict-key engine flags access to

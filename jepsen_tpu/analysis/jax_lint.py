@@ -19,8 +19,8 @@ JAX-HOST-CAST               warning   ``float()/int()/bool()`` on a
                                       (a concretization point)
 JAX-UNHASHABLE-STATIC       error     a list/dict/set literal passed to
                                       an ``lru_cache``'d jit factory
-                                      (``_jit_single``/``_jit_segment``/
-                                      ``_jit_batch``): unhashable keys
+                                      (``_jit_segment``/``_jit_batch``):
+                                      unhashable keys
                                       raise — or, worse, near-miss keys
                                       defeat the compile cache
 JAX-INT32-OVERFLOW          error     a compile-time integer outside

@@ -1,18 +1,18 @@
 """Explicit search-executable engine: the warm heart of `jtpu serve`.
 
-Before this module the compiled search executables lived in three
+Before this module the compiled search executables lived in
 ``functools.lru_cache``'d factories inside :mod:`jepsen_tpu.checker.tpu`
-(``_jit_single`` / ``_jit_segment`` / ``_jit_batch``) — adequate for a
+(``_jit_segment`` / ``_jit_batch``) — adequate for a
 one-shot CLI process, but invisible and unmanageable for a long-lived
 daemon: no way to enumerate what is warm, warm a shape ahead of the
 first tenant request, persist compilations across restarts, or evict.
 
 The :class:`Engine` makes the executable cache an explicit object:
 
-* **Same keying, same executables** — :meth:`jit_single` /
-  :meth:`jit_segment` / :meth:`jit_batch` take exactly the arguments the
-  lru_cache'd factories took and build exactly the same ``jax.jit``
-  closures; the tpu-module functions now delegate here, so every
+* **Same keying, same executables** — :meth:`jit_segment` /
+  :meth:`jit_batch` / :meth:`jit_batch_segment` take exactly the
+  arguments the lru_cache'd factories took and build exactly the same
+  ``jax.jit`` closures; the tpu-module functions delegate here, so every
   existing call site (resilience, fleet, plan's zero-compile probes,
   chaos monkeypatches) is unchanged in behavior.
 * **Shape buckets** — :meth:`bucket_key` names the padded-shape bucket a
@@ -48,7 +48,7 @@ import logging
 import os
 import threading
 import time
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional
 
 import numpy as np
 
@@ -174,41 +174,13 @@ class Engine:
                 _ENGINE_HITS.inc()
         return fn
 
-    def jit_single(self, kernel_id: int, capacity: int, window: int,
-                   expand: Optional[int] = None, unroll: int = 1,
-                   shard_axis: Optional[str] = None,
-                   stats: bool = False):
-        """The monolithic single-history executable (one while_loop to
-        a verdict) — body identical to the pre-Engine ``_jit_single``.
-        ``stats=True`` compiles the per-level counter lane
-        (T.SEARCHSTAT_COLS) and returns it as a 9th output; the flag is
-        part of the cache key so counters-off callers keep the original
-        executable."""
-        import jax
-        kernel = T._KERNELS_BY_ID[kernel_id]
-
-        def build():
-            def single(f, v1, v2, ro, fr, inv, ret, sm, cf, cv1, cv2,
-                       cinv, cps, nr, ini):
-                search = T._search_fn(kernel.step, f.shape[0],
-                                      cf.shape[0], capacity, window,
-                                      expand, unroll, shard_axis,
-                                      stats=stats)
-                return search(f, v1, v2, ro, fr, inv, ret, sm, cf, cv1,
-                              cv2, cinv, cps, nr, ini)
-
-            return jax.jit(single)
-
-        return self._get(("single", kernel_id, capacity, window, expand,
-                          unroll, shard_axis, stats), build)
-
     def jit_segment(self, kernel_id: int, capacity: int, window: int,
                     expand: Optional[int] = None, unroll: int = 1,
                     shard_axis: Optional[str] = None,
                     stats: bool = False):
         """One bounded-iteration checkpointed segment (the supervised
-        mode's executable; traced seg_iters, so changing segment length
-        never recompiles) — body identical to ``_jit_segment``.
+        executable of every single-history search, sharded or not;
+        traced seg_iters, so changing segment length never recompiles).
         ``stats=True`` carries the per-level counter lane as a 14th
         carry element (extracted host-side at segment barriers)."""
         import jax
@@ -420,9 +392,9 @@ class Engine:
              segment_iters: Optional[int] = None) -> Dict[str, Any]:
         """Warm the escalation ladder for this history's shape bucket.
 
-        For each rung of the bucket universe (the same ladder
-        ``check_packed_tpu`` / the supervised search would escalate
-        through — :func:`jepsen_tpu.checker.tpu._ladder_for` at the
+        For each rung of the bucket universe (the same ladder the
+        supervised search would escalate through —
+        :func:`jepsen_tpu.checker.tpu._ladder_for` at the
         history's needed window, i.e. exactly the candidates
         :func:`jepsen_tpu.checker.plan.enumerate_candidates` prices):
 
@@ -490,8 +462,7 @@ class Engine:
             cols["nr"] = np.int32(0)
             full = T._ladder_for(T._window_needed(p))
             ladder = full[:rungs] if rungs else full
-            seg = (segment_iters if segment_iters is not None
-                   else T._segment_config(None))
+            seg = T._segment_config(segment_iters)
             kid = T._kernel_key(kernel)
             unroll = T._unroll_factor()
             # warm the executable real calls will select: with tracing
@@ -501,24 +472,16 @@ class Engine:
             lmax = T._level_budget(cols["f"].shape[0],
                                    cols["cf"].shape[0])
             for cap, win, exp in ladder:
-                if seg:
-                    fn = self.jit_segment(kid, cap, win, exp, unroll,
-                                          stats=stats)
-                    carry = T._carry0_host(
-                        cap, win, cols["cf"].shape[0], cols["ini"], 0,
-                        stats_rows=(lmax + 1) if stats else 0)
-                    args = ([cols[c] for c in T._COLS]
-                            + [np.int32(seg), carry])
-                    shape_key = ("segment", kid, cap, win, exp, unroll,
-                                 cols["f"].shape[0], cols["cf"].shape[0],
-                                 stats)
-                else:
-                    fn = self.jit_single(kid, cap, win, exp, unroll,
-                                         stats=stats)
-                    args = [cols[c] for c in T._COLS]
-                    shape_key = ("single", kid, cap, win, exp, unroll,
-                                 cols["f"].shape[0], cols["cf"].shape[0],
-                                 stats)
+                fn = self.jit_segment(kid, cap, win, exp, unroll,
+                                      stats=stats)
+                carry = T._carry0_host(
+                    cap, win, cols["cf"].shape[0], cols["ini"], 0,
+                    stats_rows=(lmax + 1) if stats else 0)
+                args = ([cols[c] for c in T._COLS]
+                        + [np.int32(seg), carry])
+                shape_key = ("segment", kid, cap, win, exp, unroll,
+                             cols["f"].shape[0], cols["cf"].shape[0],
+                             stats)
                 try:
                     # AOT compile: feeds the persistent cache; cheap to
                     # follow with the trivial execution, which fills the
@@ -555,7 +518,7 @@ def configure_compile_cache() -> str:
     other), else :data:`DEFAULT_CACHE_DIR`. Thresholds are dropped to
     zero so small kernels persist too. :class:`Engine` calls this when
     it is made, so every path that compiles a search (run/analyze,
-    the daemon, fleet workers, bench, chip_smoke.py) gets the cache and
+    the daemon, fleet workers, chip_smoke.py) gets the cache and
     paths that never compile do not import JAX for it."""
     import jax
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_DIR
@@ -574,9 +537,9 @@ _DEFAULT_LOCK = threading.Lock()
 
 
 def default_engine() -> Engine:
-    """The process-global Engine behind ``_jit_single`` / ``_jit_segment``
-    / ``_jit_batch``. Created lazily — importing this module compiles
-    nothing."""
+    """The process-global Engine behind ``_jit_segment`` / ``_jit_batch``
+    / ``_jit_batch_segment``. Created lazily — importing this module
+    compiles nothing."""
     global _DEFAULT
     with _DEFAULT_LOCK:
         if _DEFAULT is None:

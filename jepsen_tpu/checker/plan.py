@@ -51,7 +51,7 @@ admission-control daemon of ROADMAP item 1).
 from __future__ import annotations
 
 import os
-from dataclasses import dataclass, replace
+from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -148,7 +148,7 @@ class PlanDims:
 class Candidate:
     """One concrete executable shape the search could run: a ladder rung
     bound to its padded buckets. ``kind`` matches the jit factory that
-    would compile it (single / segment / batch / sharded)."""
+    would compile it (segment / batch / sharded)."""
 
     kind: str
     capacity: int
@@ -212,7 +212,7 @@ def enumerate_candidates(dims: PlanDims,
     With explicit capacity/window/expand the universe collapses to the
     pinned rung (what check_*_tpu would run); otherwise it is the full
     escalation ladder at the history's needed window. ``kinds`` defaults
-    to (single, segment) for one key, (batch,) for keyed dims, plus
+    to (segment,) for one key, (batch,) for keyed dims, plus
     (sharded,) when ``mesh_axis`` is given."""
     nr = max(dims.n_required, 1)
     breq = T._bucket(nr)
@@ -221,7 +221,7 @@ def enumerate_candidates(dims: PlanDims,
         return []  # crashed-set overflow: a dims-level finding, no plans
     unroll = T._unroll_factor()
     if kinds is None:
-        kinds = (("batch",) if dims.keys > 1 else ("single", "segment"))
+        kinds = ("batch",) if dims.keys > 1 else ("segment",)
         if mesh_axis:
             kinds = tuple(kinds) + ("sharded",)
     out: List[Candidate] = []
@@ -230,11 +230,11 @@ def enumerate_candidates(dims: PlanDims,
     else:
         ladder = T._ladder_for(max(dims.window_needed, 1))
     for kind in kinds:
-        if kind in ("single", "segment"):
+        if kind == "segment":
             for cap, win, exp in ladder:
-                out.append(Candidate(kind=kind, capacity=cap, window=win,
-                                     expand=exp, unroll=unroll,
-                                     breq=breq, crw=crw))
+                out.append(Candidate(kind="segment", capacity=cap,
+                                     window=win, expand=exp,
+                                     unroll=unroll, breq=breq, crw=crw))
         elif kind == "batch":
             if capacity is not None:
                 klad = ladder
@@ -321,7 +321,8 @@ def footprint(cand: Candidate) -> Dict[str, int]:
         terms = 2 + MW + 1 + (1 + mcr if CR else 0)
     sort = 2 * merged * terms * 4
     carry = carry_nbytes(C, W, CR)
-    ncarry = 3 if cand.kind == "segment" else 2  # seg: carry is an input too
+    # a segment executable takes the carry as an input too
+    ncarry = 3 if cand.kind in ("segment", "sharded") else 2
     per_key = ncarry * carry + grid + sort
     cols = cols_nbytes(cand.breq, CR, cand.keys)
     total = cols + per_key * cand.keys
@@ -523,16 +524,7 @@ def trace_candidate(cand: Candidate, kernel, cost: bool = False,
     Returns ``{"ok", "error", "cost"}``; memoized per bucket. A sharded
     candidate needs a real mesh to trace (with_sharding_constraint); when
     none is supplied the result is ``ok=None`` (untraceable here, not
-    broken).
-
-    Without ``cost``, a ``single`` candidate reads its ``segment``
-    twin's trace: both executables wrap one ``_search_fn`` body (carry
-    set-up, level step, loop), and the segment's trace runs all of it
-    plus the checkpoint carry's layout, so tracing the body twice finds
-    nothing new."""
-    if cand.kind == "single" and not cost:
-        return trace_candidate(replace(cand, kind="segment"), kernel,
-                               mesh=mesh)
+    broken)."""
     key = (T._kernel_key(kernel), cand.kind, cand.capacity, cand.window,
            cand.expand, cand.unroll, cand.breq, cand.crw, cand.keys,
            cand.tiebreak, cand.mesh_axis, bool(cost))
@@ -547,30 +539,23 @@ def trace_candidate(cand: Candidate, kernel, cost: bool = False,
     import jax
     kid = T._kernel_key(kernel)
     try:
-        if cand.kind == "segment":
-            fn = T._jit_segment(kid, cand.capacity, cand.window,
-                                cand.expand, cand.unroll)
-            args = (_col_structs(cand, jax)
-                    + [jax.ShapeDtypeStruct((), np.int32),
-                       _carry_structs(cand, jax)])
-        elif cand.kind == "batch":
+        if cand.kind == "batch":
             fn = T._jit_batch(kid, cand.capacity, cand.window,
                               cand.expand, cand.unroll,
                               tiebreak=cand.tiebreak)
             args = _col_structs(cand, jax)
-        elif cand.kind == "sharded":
-            if mesh is None:
+        else:
+            if cand.kind == "sharded" and mesh is None:
                 out["error"] = ("sharded bucket needs a mesh to trace; "
                                 "arithmetic checks only")
                 _TRACE_MEMO[key] = out
                 return dict(out)
-            fn = T._jit_single(kid, cand.capacity, cand.window,
-                               cand.expand, cand.unroll, T.POOL_AXIS)
-            args = _col_structs(cand, jax)
-        else:
-            fn = T._jit_single(kid, cand.capacity, cand.window,
-                               cand.expand, cand.unroll)
-            args = _col_structs(cand, jax)
+            axis = T.POOL_AXIS if cand.kind == "sharded" else None
+            fn = T._jit_segment(kid, cand.capacity, cand.window,
+                                cand.expand, cand.unroll, axis)
+            args = (_col_structs(cand, jax)
+                    + [jax.ShapeDtypeStruct((), np.int32),
+                       _carry_structs(cand, jax)])
 
         def run():
             jax.eval_shape(fn, *args)

@@ -85,8 +85,7 @@ _UNROLL = 1
 #: jepsen_tpu.resilience): the single-history search runs as an outer host
 #: loop of bounded device segments, snapshotting the carry to host between
 #: them so a crashed / wedged / preempted search resumes where it left off
-#: instead of losing everything. 0 disables segmentation (one monolithic
-#: while_loop, the pre-resilience behavior).
+#: instead of losing everything.
 DEFAULT_SEGMENT_ITERS = 1024
 
 
@@ -190,7 +189,11 @@ def _search_fn(step, n: int, n_cr: int, capacity: int, window: int,
        pool_k, pool_state, pool_alive)
     — five jnp scalars plus the last living pool's [capacity] columns
     (the frontier configs counterexample extraction reads on
-    valid:false). Pure jnp — safe under jit, vmap, and shard_map.
+    valid:false). Pure jnp — safe under jit, vmap, and shard_map. This
+    runs the whole search in one while_loop; the keyed batch
+    (Engine.jit_batch) vmaps it. ``segment=True`` instead takes a
+    traced level bound and a carry and returns the carry: the
+    checkpointed segments every single-history search runs.
     ``ro[j]`` is 1 iff op j is *read-only* — its step can never change the
     state at any state where it succeeds (kernel.readonly) — which drives
     the greedy pure-op closure below.
@@ -230,9 +233,8 @@ def _search_fn(step, n: int, n_cr: int, capacity: int, window: int,
     ``stats=True`` appends one extra carry lane: a ``[LMAX+1, NSTAT]``
     int32 per-level counter log (:data:`SEARCHSTAT_COLS`) written with
     pure ``.at[].set`` indexing inside the traced body — zero host sync;
-    the host extracts it at segment barriers (segment mode returns the
-    raw carry) or from the appended final output (monolithic mode
-    returns it as a 9th element). ``stats=False`` compiles the original
+    the host extracts it from the raw carry at segment barriers, so the
+    lane is for segment mode. ``stats=False`` compiles the original
     13-lane carry, byte-identical to the pre-analytics executable.
     """
     C, W, CR = capacity, window, n_cr
@@ -658,7 +660,7 @@ def _search_fn(step, n: int, n_cr: int, capacity: int, window: int,
         # running `unroll` search steps per iteration amortizes it (body
         # is a masked update — extra applications after completion are
         # no-ops, so correctness is unaffected). The factor is part of
-        # the jit cache key (see _jit_single/_jit_batch) so sweeps
+        # the jit cache key (see Engine.jit_segment/jit_batch) so sweeps
         # actually recompile.
 
         def body_n(c):
@@ -672,8 +674,9 @@ def _search_fn(step, n: int, n_cr: int, capacity: int, window: int,
             # the RAW carry — the host supervisor snapshots it between
             # segments (the checkpoint), decides continuation, and
             # summarizes via _summarize_carry when the search goes
-            # inactive. The body sequence is identical to the monolithic
-            # loop's, so verdicts and level counts match exactly.
+            # inactive. The body sequence is the one the whole-search
+            # loop below runs, so any segment length gives the same
+            # verdicts and level counts.
             carry = carry0 if carry_in is None else carry_in
             lvl0 = carry[8]
 
@@ -690,8 +693,6 @@ def _search_fn(step, n: int, n_cr: int, capacity: int, window: int,
         # Stopped at the iteration budget with work left: incomplete, so a
         # non-done outcome must not read as a refutation.
         lossy = lossy | (~done & jnp.any(alive_out))
-        if stats:
-            return done, lossy, wovf, best, level, pk, ps, pa, out[13]
         return done, lossy, wovf, best, level, pk, ps, pa
 
     return search
@@ -707,7 +708,7 @@ def _search_fn(step, n: int, n_cr: int, capacity: int, window: int,
 _DEVICE_SECONDS = obs_metrics.histogram(
     "jtpu_device_call_seconds",
     "wall time of one device executable call (host-side, around "
-    "block_until_ready), labeled kind=single|segment|batch|sharded and "
+    "block_until_ready), labeled kind=segment|batch|sharded and "
     "phase=compile|execute; 'compile' is the shape's first call in this "
     "process — XLA compilation plus one execution",
     buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
@@ -836,7 +837,7 @@ def compile_line(delta: Dict[str, Any],
                  wall_s: Optional[float] = None) -> str:
     """One ``# compile:`` attribution line splitting a check's
     wall-clock into cold-compile / execute / transfer — printed by
-    analyze, recover, and bench.py. ``delta`` comes from
+    analyze and recover. ``delta`` comes from
     :func:`compile_delta` around the check."""
     pc = persistent_cache_dir()
     if pc is None:
@@ -864,7 +865,7 @@ def _first_call(key: tuple) -> bool:
     First calls pay XLA compilation (the persistent compilation cache
     can shrink but not remove that phase), so their timings are recorded
     under phase="compile" and steady-state calls under "execute" — the
-    split bench.py and the ``# search:`` summary report."""
+    split the ``# compile:`` and ``# search:`` summaries report."""
     first = key not in _EXECUTED_SHAPES
     _EXECUTED_SHAPES.add(key)
     return first
@@ -927,13 +928,6 @@ def _engine():
     return engine_mod.default_engine()
 
 
-def _jit_single(kernel_id: int, capacity: int, window: int,
-                expand: Optional[int] = None, unroll: int = 1,
-                shard_axis: Optional[str] = None, stats: bool = False):
-    return _engine().jit_single(kernel_id, capacity, window, expand,
-                                unroll, shard_axis, stats)
-
-
 def _jit_segment(kernel_id: int, capacity: int, window: int,
                  expand: Optional[int] = None, unroll: int = 1,
                  shard_axis: Optional[str] = None, stats: bool = False):
@@ -942,11 +936,10 @@ def _jit_segment(kernel_id: int, capacity: int, window: int,
     columns, a traced per-call iteration bound, and the search carry;
     returns the updated carry. The bound is traced (not static), so
     changing segment length never recompiles. With ``shard_axis`` the
-    segment's pool/grids/sort rows are partitioned over the mesh axis
-    exactly like _jit_single's sharded mode — the segmented, checkpointed
-    flavor of check_packed_sharded (every segment boundary is the global
-    merge-sort barrier, so the host carry snapshot between segments IS a
-    consistent cross-host checkpoint)."""
+    segment's pool/grids/sort rows are partitioned over the mesh axis:
+    the executable of check_packed_sharded (every segment boundary is
+    the global merge-sort barrier, so the host carry snapshot between
+    segments IS a consistent cross-host checkpoint)."""
     return _engine().jit_segment(kernel_id, capacity, window, expand,
                                  unroll, shard_axis, stats)
 
@@ -1031,7 +1024,7 @@ def _summarize_carry(carry) -> tuple:
     """Host mirror of _search_fn's post-loop summary: returns (done,
     lossy, wovf, best_k, levels, pool). Stopping at the iteration budget
     with work left must not read as a refutation — exactly the
-    monolithic loop's final lossy adjustment."""
+    whole-search loop's final lossy adjustment."""
     done, lossy, wovf = bool(carry[5]), bool(carry[6]), bool(carry[7])
     lossy = lossy or (not done and bool(np.any(carry[4])))
     return (done, lossy, wovf, int(carry[9]), int(carry[8]),
@@ -1067,20 +1060,24 @@ def _fleet_hosts() -> int:
     return n if n >= 2 else 0
 
 
-def _segment_config(segment_iters: Optional[int]) -> Optional[int]:
-    """Resolve the segmentation knob: an explicit argument wins (0 =
-    disabled), then JTPU_SEGMENT_ITERS, then the module default. Returns
-    None when the monolithic while_loop should run instead."""
-    if segment_iters is not None:
-        return int(segment_iters) or None
-    env = _os_environ_get("JTPU_SEGMENT_ITERS")
-    if env is not None and env.strip():
-        try:
-            return int(env) or None
-        except ValueError:
-            raise ValueError(
-                f"JTPU_SEGMENT_ITERS must be an integer, got {env!r}")
-    return DEFAULT_SEGMENT_ITERS
+def _segment_config(segment_iters: Optional[int]) -> int:
+    """Resolve the segment length, in device levels: an explicit
+    argument wins, then JTPU_SEGMENT_ITERS, then DEFAULT_SEGMENT_ITERS.
+    Anything but a positive integer raises ValueError."""
+    name = "segment_iters"
+    if segment_iters is None:
+        env = _os_environ_get("JTPU_SEGMENT_ITERS")
+        if env is None or not env.strip():
+            return DEFAULT_SEGMENT_ITERS
+        name, segment_iters = "JTPU_SEGMENT_ITERS", env
+    try:
+        seg = int(segment_iters)
+    except ValueError:
+        seg = 0
+    if seg < 1:
+        raise ValueError(
+            f"{name} must be a positive integer, got {segment_iters!r}")
+    return seg
 
 
 def _jit_batch(kernel_id: int, capacity: int, window: int,
@@ -1300,10 +1297,9 @@ CPU_FIRST_RUNG = (32, 4)
 def _capacity_ladder():
     """The capacity/expand ladder for the active JAX backend.
 
-    JTPU_FIRST_RUNG="capacity,expand" pins the first rung explicitly —
-    the knob bench.py's first-rung sweep measures, so the winning shape
-    on a given accelerator can be deployed via env without a code
-    change."""
+    JTPU_FIRST_RUNG="capacity,expand" pins the first rung explicitly, so
+    the winning shape on a given accelerator can be deployed via env
+    without a code change."""
     import os as _os
     env = _os.environ.get("JTPU_FIRST_RUNG")
     if env:
@@ -1356,12 +1352,6 @@ def _ladder_for(wneed: int):
     return tuple((c, w, e) for c, e in _capacity_ladder())
 
 
-def _select_rungs(wneed: int):
-    """Back-compat shim over _ladder_for (kept for callers/tests that
-    reason about rung windows)."""
-    return _ladder_for(wneed)
-
-
 def _prep_single(p: PackedHistory,
                  kernel: KernelSpec) -> tuple:
     """Shared single-history preamble for check_packed_tpu and
@@ -1396,112 +1386,30 @@ def check_packed_tpu(p: PackedHistory, kernel: KernelSpec,
     With an explicit capacity, ``expand`` < capacity selects best-first
     search (None = exhaustive level-synchronous BFS).
 
-    By default the search runs SEGMENTED under the resilience supervisor
+    The search runs SEGMENTED under the resilience supervisor
     (jepsen_tpu.resilience): bounded device segments with host
     checkpoints between them, OOM shrink-and-retry, and an optional
     per-segment wedge watchdog (``deadline_s``, falling back to the CPU
-    backend mid-run). ``segment_iters`` overrides JTPU_SEGMENT_ITERS;
-    0 forces the monolithic single-while_loop path."""
+    backend mid-run). ``segment_iters`` (levels a segment, a positive
+    integer) overrides JTPU_SEGMENT_ITERS."""
     if window is not None:
         _check_window(window)
+    seg = _segment_config(segment_iters)
     nfleet = _fleet_hosts()
     if nfleet:
         # Elastic fleet opt-in (JTPU_FLEET=N, doc/resilience.md
         # "Elastic fleet"): the search runs under the fleet scheduler —
         # N logical hosts each owning a pool shard, merged at the
         # global sort barrier, surviving host loss/join/skew. Off
-        # (0/absent), this branch is never taken and the single-host
-        # paths below are untouched.
+        # (0/absent), this branch is never taken.
         from jepsen_tpu import fleet as fleet_mod
         return fleet_mod.check_packed_fleet(
             p, kernel, hosts=nfleet, capacity=capacity, window=window,
-            expand=expand, segment_iters=segment_iters)
-    seg = _segment_config(segment_iters)
-    if seg:
-        from jepsen_tpu import resilience
-        return resilience.supervised_check_packed(
-            p, kernel, capacity=capacity, window=window, expand=expand,
-            segment_iters=seg, deadline_s=deadline_s)
-    cols, early = _prep_single(p, kernel)
-    if early is not None:
-        return early
-    # Mandatory pre-search plan gate (doc/plan.md), next to the PR-3
-    # history gate: prove every rung fits the byte budget and encodes
-    # inside int32 BEFORE any jit factory is touched. Invalid rungs are
-    # filtered (recorded in the result's "plan" entry, cheapest valid
-    # rung first); a fully-rejected ladder raises PlanRejectedError.
-    # Kill switch: JTPU_PLAN_GATE=0.
-    from jepsen_tpu.checker import plan as plan_mod
-    plan_entry = None
-    with obs.span("checker.plan"):
-        if capacity is not None:
-            _check_window(window or WINDOW)
-            ladder = ((capacity, window or WINDOW, expand),)
-        else:
-            ladder = _ladder_for(_window_needed(p))
-        if plan_mod.gate_enabled():
-            ladder, plan_entry = plan_mod.gate_ladder(
-                p, kernel, ladder, kind="single",
-                explicit=capacity is not None,
-                where="the monolithic device search")
-    # Opt-in device profiling (doc/observability.md "Device
-    # profiling"): a no-op unless JTPU_PROF=1 and a run dir is armed.
-    with obs_profiler.capture():
-        return _check_packed_ladder(p, kernel, ladder, cols, plan_entry)
-
-
-def _check_packed_ladder(p, kernel, ladder, cols,
-                         plan_entry) -> Dict[str, Any]:
-    from jepsen_tpu.obs import searchstats as obs_searchstats
-    out: Dict[str, Any] = {}
-    work: list = []
-    # Search analytics (doc/observability.md): with tracing on, the
-    # single-history executable carries the per-level counter lane and
-    # returns it as a 9th output; JTPU_TRACE=0 keeps the stats-off
-    # executable (separate cache key), so verdicts and artifacts stay
-    # byte-identical to the pre-analytics tree.
-    stats = obs.enabled()
-    for cap, win, exp in ladder:
-        unroll = _unroll_factor()
-        fn = _jit_single(_kernel_key(kernel), cap, win, exp, unroll,
-                         stats=stats)
-        shape_key = ("single", _kernel_key(kernel), cap, win, exp,
-                     unroll, cols["f"].shape[0], cols["cf"].shape[0],
-                     stats)
-        outs, _, _ = _timed_call(
-            "single", shape_key, fn, [cols[c] for c in _COLS],
-            rung=(cap, win, exp))
-        if stats:
-            done, lossy, wovf, best, levels, pk, ps, pa, slog = outs
-        else:
-            done, lossy, wovf, best, levels, pk, ps, pa = outs
-            slog = None
-        _LEVELS_TOTAL.inc(int(levels))
-        out = _result(bool(done), bool(lossy), bool(wovf), int(best),
-                      int(levels), p, pool=(pk, ps, pa))
-        # the rung that produced this verdict, for utilization
-        # accounting (bench.py derives per-level work from it); "work"
-        # additionally lists EVERY rung this search burned levels on, so
-        # escalated searches don't hide their early-rung spend
-        out["rung"] = (cap, win, exp)
-        out["crash-width"] = _crash_width(p.n - p.n_required) or 0
-        out["tiebreak"] = "lex"
-        work.append(((cap, win, exp), out["crash-width"], "lex",
-                     int(levels)))
-        out["work"] = list(work)
-        if plan_entry is not None:
-            out["plan"] = plan_entry
-        if slog is not None:
-            # roll the counter log up into the result (and, when a run
-            # directory is attached, searchstats.json + the live bits)
-            lv = np.asarray(slog)[:int(levels)]
-            obs_searchstats.record(lv, rung=(cap, win, exp))
-            out["searchstats"] = obs_searchstats.rollup(lv)
-        if out["valid"] is not UNKNOWN:
-            return out
-        if bool(wovf) and win >= MAX_WINDOW and not bool(lossy):
-            return out  # a bigger frontier won't fix a window overflow
-    return out
+            expand=expand, segment_iters=seg)
+    from jepsen_tpu import resilience
+    return resilience.supervised_check_packed(
+        p, kernel, capacity=capacity, window=window, expand=expand,
+        segment_iters=seg, deadline_s=deadline_s)
 
 
 #: Fault-injection seam for the gang dispatch path (the batched twin of
@@ -1560,9 +1468,7 @@ def check_packed_gang(pks: Sequence[PackedHistory], kernel: KernelSpec,
     groups = _gang_groups(pks, results)
     if not groups:
         return results
-    # gangs always run segmented: the segment barrier IS the per-member
-    # cancellation point, so a 0/monolithic config still segments
-    seg = _segment_config(segment_iters) or DEFAULT_SEGMENT_ITERS
+    seg = _segment_config(segment_iters)
     for ladder, idx in groups.items():
         _gang_ladder(pks, kernel, idx, ladder, seg, deadlines, results)
     return results
@@ -1723,7 +1629,7 @@ def check_packed_gang_fleet(pks: Sequence[PackedHistory],
     groups = _gang_groups(pks, results)
     if not groups:
         return results
-    seg = _segment_config(segment_iters) or DEFAULT_SEGMENT_ITERS
+    seg = _segment_config(segment_iters)
     for ladder, idx in groups.items():
         _gang_ladder_fleet(pks, kernel, idx, ladder, seg, deadlines,
                            results, hosts, on_round, max_retries,
@@ -2016,8 +1922,9 @@ def check_packed_sharded(p: PackedHistory, kernel: KernelSpec,
     picks the history's needed bucket. Returns the same result dict as
     check_packed_tpu.
 
-    With ``segment_iters`` the sharded search runs CHECKPOINTED: an
-    outer host loop of bounded device segments (the sharded flavor of
+    The sharded search runs CHECKPOINTED: an outer host loop of
+    bounded device segments of ``segment_iters`` levels (None =
+    JTPU_SEGMENT_ITERS or DEFAULT_SEGMENT_ITERS; the sharded flavor of
     _jit_segment), snapshotting the carry to host after every segment —
     every segment boundary is the global merge-sort barrier, so the
     snapshot is a consistent cross-host checkpoint (gathered over DCN
@@ -2026,8 +1933,9 @@ def check_packed_sharded(p: PackedHistory, kernel: KernelSpec,
     ``resume`` continues one — including on a mesh of a DIFFERENT axis
     size than the one that saved it (the carry is global state; the
     axis only partitions its rows), which is what the elastic fleet
-    layer's re-meshing leans on. The body sequence is identical to the
-    monolithic sharded loop's, so verdicts and level counts match."""
+    layer's re-meshing leans on. The body sequence is the one-device
+    search's, so verdicts and level counts match it."""
+    seg = _segment_config(segment_iters)
     naxis = mesh.shape[POOL_AXIS]
     cols, early = _prep_single(p, kernel)
     if early is not None:
@@ -2057,49 +1965,9 @@ def check_packed_sharded(p: PackedHistory, kernel: KernelSpec,
         raise ValueError(
             f"the mesh axis ({naxis}) must divide capacity "
             f"({capacity}) and expand ({expand})")
-    if segment_iters:
-        return _check_sharded_segmented(
-            p, kernel, mesh, naxis, cols, capacity, window, expand,
-            int(segment_iters), checkpoint_path, on_checkpoint, resume,
-            plan_entry)
-    fn = _jit_single(_kernel_key(kernel), capacity, window, expand,
-                     _unroll_factor(), POOL_AXIS)
-    with _mesh_context(mesh):
-        shape_key = ("sharded", _kernel_key(kernel), capacity, window,
-                     expand, naxis, cols["f"].shape[0],
-                     cols["cf"].shape[0])
-        outs, _, _ = _timed_call(
-            "sharded", shape_key, fn, [cols[c] for c in _COLS],
-            rung=(capacity, window, expand), axis=naxis)
-        done, lossy, wovf, best, levels, pk, ps, pa = outs
-        _LEVELS_TOTAL.inc(int(levels))
-        done, lossy, wovf = bool(done), bool(lossy), bool(wovf)
-        pool = (pk, ps, pa)
-        if jax.process_count() > 1:
-            # The scalar outputs are replicated (readable everywhere),
-            # but the pool columns are row-sharded over the mesh axis —
-            # on a multi-host mesh they are not fully addressable and
-            # np.asarray in _result would raise. They are only read for
-            # a clean refutation, so gather exactly then.
-            if not done and not lossy and not wovf:
-                from jax.experimental import multihost_utils
-                pool = tuple(
-                    multihost_utils.process_allgather(x, tiled=True)
-                    for x in pool)
-            else:
-                pool = None
-        out = _result(done, lossy, wovf, int(best),
-                      int(levels), p, pool=pool)
-        if pool is not None:
-            # straggler accounting: live rows + deepest config per
-            # mesh-axis shard, and the max/mean imbalance ratio
-            balance = _shard_balance(pool, naxis)
-            if balance is not None:
-                out["shard-balance"] = balance
-    out["pool-sharding"] = f"{POOL_AXIS}={naxis}"
-    if plan_entry is not None:
-        out["plan"] = plan_entry
-    return out
+    return _check_sharded_segmented(
+        p, kernel, mesh, naxis, cols, capacity, window, expand, seg,
+        checkpoint_path, on_checkpoint, resume, plan_entry)
 
 
 def _check_sharded_segmented(p, kernel, mesh, naxis: int, cols: dict,

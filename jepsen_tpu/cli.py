@@ -85,8 +85,8 @@ def add_test_opts(p: argparse.ArgumentParser) -> None:
     p.add_argument("--segment-iters", type=int, default=None,
                    metavar="N",
                    help="device-search iterations per checkpointed "
-                        "segment (resilient execution; 0 = one "
-                        "monolithic device call)")
+                        "segment (resilient execution; a positive "
+                        "integer)")
     p.add_argument("--watch", action="store_true",
                    help="print a live search-progress status line "
                         "(level/frontier/ETA) to stderr while the "
@@ -168,9 +168,13 @@ def _apply_fleet(n):
 def _apply_segment_iters(seg):
     """Deploy --segment-iters: the device checkers read the segmentation
     knob from JTPU_SEGMENT_ITERS (like the other JTPU_* tuning knobs), so
-    the flag exports it for every check this process runs."""
+    the flag exports it for every check this process runs. A value that
+    is not a positive integer raises ValueError before anything runs."""
     if seg is not None:
         import os
+        if seg < 1:
+            raise ValueError(
+                f"--segment-iters must be a positive integer, got {seg}")
         os.environ["JTPU_SEGMENT_ITERS"] = str(seg)
     return seg
 
@@ -595,7 +599,7 @@ def _add_analysis_opts(p: argparse.ArgumentParser) -> None:
     p.add_argument("--segment-iters", type=int, default=None,
                    metavar="N",
                    help="device-search iterations per checkpointed "
-                        "segment (0 = monolithic)")
+                        "segment (a positive integer)")
     p.add_argument("--profile", action="store_true",
                    help="capture a jax.profiler device trace of the "
                         "re-check into <run>/profile/ (JTPU_PROF=1)")

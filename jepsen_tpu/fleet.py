@@ -1,7 +1,7 @@
 """Elastic fleet scheduling: host-loss-tolerant re-meshing and
 work-stealing rebalance for multi-host device search.
 
-The MULTICHIP_r* runs proved 2-host DCN pool sharding end to end, and
+Pool sharding runs across hosts over DCN (check_packed_sharded), and
 the observatory measures straggler skew (``jtpu_shard_imbalance_ratio``)
 — but a single host loss still killed the whole pool-sharded search,
 and nobody acted on the imbalance gauge. This module turns the PR-1
@@ -1066,8 +1066,7 @@ class ElasticFleet:
         from jepsen_tpu.checker import plan as plan_mod
         if window is not None:
             T._check_window(window)
-        seg = (segment_iters or T._segment_config(None)
-               or T.DEFAULT_SEGMENT_ITERS)
+        seg = T._segment_config(segment_iters)
         cols, early = T._prep_single(p, kernel)
         if early is not None:
             early["fleet"] = self._fleet_entry()

@@ -1,6 +1,6 @@
 """Fleet telemetry: merge N hosts' run artifacts into one view.
 
-The MULTICHIP_r* two-host runs each produce a per-host artifact set —
+A multi-host run produces one artifact set per host —
 ``trace.jsonl`` + ``metrics.json`` + ``progress.json`` in that host's
 run directory — and until this module nothing correlated them: two
 disjoint timelines, two metric registries, two progress heartbeats.

@@ -72,8 +72,8 @@ CAPTURE_SPAN = "prof.capture"
 SYNC = "jtpu.sync"
 
 #: Host span names a device record may be parented under (deepest wins).
-HOST_PARENTS = ("checker.segment", "checker.device.single",
-                "checker.device.batch", "checker.device.sharded")
+HOST_PARENTS = ("checker.segment", "checker.device.batch",
+                "checker.device.sharded")
 
 #: Synthetic tid base for device lanes in merged records (far above any
 #: OS thread id's low bits colliding in the same waterfall row).
@@ -200,8 +200,8 @@ class _Capture:
 def capture() -> _Capture:
     """``with profiler.capture(): <device search>`` — a no-op unless
     JTPU_PROF is on and a run directory is armed. Nested captures are
-    no-ops (the outermost wins), so both the supervised search and the
-    monolithic path may wrap unconditionally."""
+    no-ops (the outermost wins), so every device path may wrap
+    unconditionally."""
     return _Capture()
 
 

@@ -9,8 +9,8 @@ rows, dedup kills, dominance kills, truncation losses, live frontier
 width), extracted host-side at segment barriers / final outputs — never
 inside the traced body. This module is the host half:
 
-* :func:`rollup` — the scalar summary attached to checker results and
-  BENCH_r*.json (frontier-area, duplicate-rate, prune-efficiency);
+* :func:`rollup` — the scalar summary attached to checker results
+  (frontier-area, duplicate-rate, prune-efficiency);
 * a run-scoped sink mirroring the full per-level series to
   ``searchstats.json`` (tmp+replace, throttled — torn-tolerant like
   progress.json), which ``jtpu explain`` and the web UI read from

@@ -55,12 +55,12 @@ import numpy as np
 from jepsen_tpu import obs
 from jepsen_tpu.checker import UNKNOWN
 from jepsen_tpu.checker import tpu as T
-from jepsen_tpu.models.core import KernelSpec, Model
+from jepsen_tpu.models.core import KernelSpec
 from jepsen_tpu.obs import devices as obs_devices
 from jepsen_tpu.obs import metrics as obs_metrics
 from jepsen_tpu.obs import observatory as obs_observatory
 from jepsen_tpu.obs import searchstats as obs_searchstats
-from jepsen_tpu.ops.encode import PackedHistory, pack_with_init
+from jepsen_tpu.ops.encode import PackedHistory
 
 log = logging.getLogger("jepsen.resilience")
 
@@ -646,7 +646,7 @@ def _supervised_check_packed(p: PackedHistory, kernel: KernelSpec,
                              ) -> Dict[str, Any]:
     if window is not None:
         T._check_window(window)
-    seg = segment_iters or T._segment_config(None) or T.DEFAULT_SEGMENT_ITERS
+    seg = T._segment_config(segment_iters)
     cols, early = T._prep_single(p, kernel)
     if early is not None:
         return early
@@ -690,8 +690,8 @@ def _supervised_check_packed(p: PackedHistory, kernel: KernelSpec,
     # Search telemetry accumulated across rungs and surfaced in the
     # result (doc/observability.md): compile/execute wall split,
     # per-segment level advances, frontier-width high-water mark, and
-    # transfer-byte accounting — what lets bench.py and the `# search:`
-    # summary attribute wall-clock to compile/device/host phases.
+    # transfer-byte accounting — what lets the `# search:` summary
+    # attribute wall-clock to compile/device/host phases.
     device_s = {"compile": 0.0, "execute": 0.0}
     seg_levels: list = []
     frontier_hwm = 0
@@ -988,7 +988,7 @@ def _supervised_check_packed(p: PackedHistory, kernel: KernelSpec,
         # split (host-measured around block_until_ready), per-segment
         # level advances, the frontier-width high-water mark, and
         # bytes shipped to/from the device — what `# search:` summaries
-        # and bench.py read to attribute wall-clock.
+        # read to attribute wall-clock.
         out["device-s"] = {k: round(v, 6) for k, v in device_s.items()}
         out["segment-levels"] = list(seg_levels)
         out["frontier-hwm"] = frontier_hwm
@@ -1009,16 +1009,3 @@ def _supervised_check_packed(p: PackedHistory, kernel: KernelSpec,
             return out  # a bigger frontier won't fix a window overflow
     return out
 
-
-def supervised_check_history(history, model: Model,
-                             **kwargs) -> Optional[Dict[str, Any]]:
-    """Pack + supervised check (see supervised_check_packed). None when
-    the model has no integer kernel."""
-    try:
-        pk = pack_with_init(history, model)
-    except ValueError:
-        return None
-    if pk is None:
-        return None
-    packed, kernel = pk
-    return supervised_check_packed(packed, kernel, **kwargs)

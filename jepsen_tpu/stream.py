@@ -439,8 +439,7 @@ class StreamRunner(threading.Thread):
         self.on_done = on_done
         self.checkpoint_path = os.path.join(session.dir, CHECKPOINT_NAME)
         self._halt = threading.Event()
-        self._seg = (segment_iters or T._segment_config(None)
-                     or T.DEFAULT_SEGMENT_ITERS)
+        self._seg = T._segment_config(segment_iters)
         self._deadline_s = deadline_s
         self._policy = R.RetryPolicy()
         self._resume_cp = None

@@ -371,8 +371,8 @@ def simulate_register_history(n_ops: int, n_procs: int = 5, n_vals: int = 8,
     """Synthesize a concurrent CAS-register history that is linearizable by
     construction: ops take effect at a random *commit* instant between their
     invocation and completion events (the linearization point), against one
-    true register. Used by bench.py (the north-star workload shape: etcd-style
-    CAS register, reference etcd.clj:149-188) and by checker stress tests.
+    true register: the north-star workload shape (etcd-style CAS register,
+    reference etcd.clj:149-188) that the checker stress tests drive.
 
     n_ops counts operations (invoke/complete pairs); the returned History has
     ~2*n_ops event rows.

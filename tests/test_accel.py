@@ -26,9 +26,11 @@ ENTRY_POINTS = {
                ".check({}, h)\n"),
     "single": ("from jepsen_tpu.checker.tpu import check_history_tpu\n"
                "r = check_history_tpu(h, CASRegister())\n"),
-    "monolithic": ("from jepsen_tpu.checker.tpu import check_history_tpu\n"
-                   "r = check_history_tpu(h, CASRegister(), "
-                   "segment_iters=0)\n"),
+    "sharded": ("from jax.sharding import AbstractMesh\n"
+                "from jepsen_tpu.checker.tpu import check_history_sharded\n"
+                "mesh = AbstractMesh((2,), ('pool',))\n"
+                "r = check_history_sharded(h, CASRegister(), mesh, "
+                "capacity=64, expand=8)\n"),
     "keyed": ("from jepsen_tpu.checker.tpu import check_keyed_tpu\n"
               "r = check_keyed_tpu({0: h, 1: h}, CASRegister())\n"),
 }

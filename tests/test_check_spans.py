@@ -82,11 +82,12 @@ def test_segmented_single_check_has_one_root():
 
 
 def test_monolithic_single_check_has_one_root():
+    # the default segment length covers this search in one device call
     h = _history(seed=4)
-    out, recs = _traced(lambda: check_history_tpu(h, CASRegister(),
-                                                  segment_iters=0))
-    assert out["valid"] is True
-    _assert_nested(recs, "checker.device.single")
+    out, recs = _traced(lambda: check_history_tpu(h, CASRegister()))
+    assert out["valid"] is True and out["segments"] == 1
+    _assert_nested(recs, "checker.segment")
+    assert sum(r["name"] == "checker.segment" for r in recs) == 1
 
 
 def test_sharded_single_check_has_one_root():

@@ -93,23 +93,12 @@ def _fits(compiled) -> None:
     assert 0 < used < V5E_HBM_BYTES, m
 
 
-@pytest.mark.parametrize("capacity,expand", [(128, 8), (1024, 64)])
-def test_single_search_compiles_at_headline_shape(one_chip, capacity,
-                                                  expand):
+@pytest.mark.parametrize("cap,exp", [(128, 8), (1024, 64)])
+def test_segmented_step_compiles_at_headline_shape(one_chip, cap, exp):
+    # the chip ladder's first two rungs (CAPACITY_LADDER)
     from jepsen_tpu.checker import tpu as T
     from jepsen_tpu.checker.engine import Engine
     cols, kernel = _headline_cols()
-    fn = Engine("chip-compile").jit_single(
-        T._kernel_key(kernel), capacity, T.WINDOW, expand, stats=True)
-    args = _abstract([cols[c] for c in T._COLS], one_chip)
-    _fits(fn.lower(*args).compile())
-
-
-def test_segmented_step_compiles_at_headline_shape(one_chip):
-    from jepsen_tpu.checker import tpu as T
-    from jepsen_tpu.checker.engine import Engine
-    cols, kernel = _headline_cols()
-    cap, exp = T.CAPACITY_LADDER[0]
     fn = Engine("chip-compile").jit_segment(
         T._kernel_key(kernel), cap, T.WINDOW, exp, stats=True)
     lmax = T._level_budget(cols["f"].shape[0], cols["cf"].shape[0])

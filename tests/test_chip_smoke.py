@@ -121,3 +121,16 @@ def test_script_alone_fails(tmp_path):
     doc = json.loads(pr.stdout.strip().splitlines()[-1])
     assert doc["ok"] is False
     assert "ModuleNotFoundError" in doc["error"]
+
+
+def test_graft_entry_compiles_single_device():
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    pr = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; jax.config.update('jax_platforms', 'cpu'); "
+         "import __graft_entry__ as g; fn, args = g.entry(); "
+         "print(jax.jit(fn)(*args))"],
+        env=env, cwd=REPO, capture_output=True, text=True,
+        timeout=300)
+    assert pr.returncode == 0, pr.stderr[-800:]

@@ -45,6 +45,24 @@ class TestOptionProcessing:
         assert os.environ["JTPU_SEGMENT_ITERS"] == "256"
         monkeypatch.delenv("JTPU_SEGMENT_ITERS", raising=False)
 
+    @pytest.mark.parametrize("value", ["0", "-2"])
+    @pytest.mark.parametrize("opts", ["test", "analysis"])
+    def test_segment_iters_must_be_positive(self, monkeypatch, opts,
+                                            value):
+        monkeypatch.delenv("JTPU_SEGMENT_ITERS", raising=False)
+        p = cli.Parser(prog="t")
+        if opts == "test":
+            cli.add_test_opts(p)
+        else:
+            cli._add_analysis_opts(p)
+        ns = p.parse_args(["--segment-iters", value])
+        with pytest.raises(ValueError, match=f"got {value}$"):
+            if opts == "test":
+                cli.test_opt_fn(vars(ns))
+            else:
+                cli._apply_segment_iters(ns.segment_iters)
+        assert "JTPU_SEGMENT_ITERS" not in os.environ
+
     def test_resilience_flags_default_off(self, monkeypatch):
         monkeypatch.delenv("JTPU_SEGMENT_ITERS", raising=False)
         p = cli.Parser(prog="t")

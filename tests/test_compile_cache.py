@@ -72,9 +72,6 @@ def test_no_code_names_a_tmp_cache():
                 with open(os.path.join(root, f)) as fh:
                     if "jepsen_tpu_jit_cache" in fh.read():
                         hits.append(f)
-    with open(os.path.join(REPO, "bench.py")) as fh:
-        if "jepsen_tpu_jit_cache" in fh.read():
-            hits.append("bench.py")
     assert not hits
 
 

@@ -88,7 +88,7 @@ class TestDockerfiles:
     def test_copy_sources_exist_or_are_staged(self, ctx):
         """Every COPY source must exist in the build context, or be one
         of the paths up.sh stages (control/jepsen_tpu etc.)."""
-        staged = {"jepsen_tpu", "tests", "bench.py", "."}
+        staged = {"jepsen_tpu", "tests", "."}
         d = os.path.join(DOCKER, ctx)
         with open(os.path.join(d, "Dockerfile")) as f:
             for line in f:

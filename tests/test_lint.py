@@ -123,9 +123,8 @@ class TestHistoryLint:
             raise AssertionError("jit factory invoked for a "
                                  "malformed history")
 
-        monkeypatch.setattr(tpu, "_jit_single", boom)
-        monkeypatch.setattr(tpu, "_jit_segment", boom)
-        monkeypatch.setattr(tpu, "_jit_batch", boom)
+        for name in ("_jit_segment", "_jit_batch", "_jit_batch_segment"):
+            monkeypatch.setattr(tpu, name, boom)
         bad = History.of([
             Op(type="invoke", f="write", value=1, process=0, time=0),
             Op(type="invoke", f="read", value=None, process=0, time=1),

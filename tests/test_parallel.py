@@ -3,6 +3,8 @@ CPU mesh from conftest."""
 
 import random
 
+import pytest
+
 from jepsen_tpu import parallel
 from jepsen_tpu.models import CASRegister
 
@@ -121,6 +123,39 @@ class TestPoolSharded:
         assert r.get("final-states")
         from jepsen_tpu.checker.tpu import POOL_AXIS
         assert r["pool-sharding"] == f"pool={mesh.shape[POOL_AXIS]}"
+
+    @pytest.mark.parametrize("refuted", [False, True],
+                             ids=["valid", "refuted"])
+    def test_default_is_segmented_and_matches_one_device(self, refuted):
+        """With no segment_iters the pool-sharded search runs its
+        checkpointed segments and answers as the one-device search of
+        the same rung does: verdict, levels and refutation evidence."""
+        from jepsen_tpu.checker.tpu import (POOL_AXIS, check_packed_sharded,
+                                            check_packed_tpu)
+        from jepsen_tpu.checker.wgl import check_packed
+        from jepsen_tpu.history import History
+        from jepsen_tpu.ops.encode import pack_with_init
+        from jepsen_tpu.testing import simulate_register_history
+        h = simulate_register_history(80, n_procs=4, n_vals=4, seed=3,
+                                      crash_p=0.02)
+        if refuted:
+            rows = list(h)
+            i = next(i for i, o in enumerate(rows)
+                     if o.type == "ok" and o.f == "read"
+                     and o.value is not None)
+            rows[i] = rows[i].replace(value=99)
+            h = History.of(rows)
+        p, kernel = pack_with_init(h, CASRegister())
+        mesh = parallel.make_mesh(2, axis=POOL_AXIS)
+        kw = dict(capacity=64, window=32, expand=16)
+        got = check_packed_sharded(p, kernel, mesh, **kw)
+        one = check_packed_tpu(p, kernel, **kw)
+        assert got["segments"] >= 1
+        assert got["valid"] is one["valid"] is (not refuted)
+        assert check_packed(p, kernel)["valid"] is (not refuted)
+        for k in ("levels", "rung", "max-linearized-prefix",
+                  "final-states"):
+            assert got.get(k) == one.get(k), k
 
     def test_divisibility_enforced(self, monkeypatch):
         import pytest as _pytest

@@ -60,11 +60,11 @@ class TestEnumeration:
         a = plan_mod.enumerate_candidates(dims)
         b = plan_mod.enumerate_candidates(dims)
         assert a == b
-        # both executable kinds, every ladder rung, nothing else
+        # the one single-history executable kind, every ladder rung,
+        # nothing else
         ladder = T._ladder_for(5)
-        assert [c.rung for c in a if c.kind == "single"] == list(ladder)
-        assert [c.rung for c in a if c.kind == "segment"] == list(ladder)
-        assert {c.kind for c in a} == {"single", "segment"}
+        assert [c.rung for c in a] == list(ladder)
+        assert {c.kind for c in a} == {"segment"}
         # buckets are the real padded widths
         assert all(c.breq == T._bucket(150) for c in a)
         assert all(c.crw == T._crash_width(3) for c in a)
@@ -73,7 +73,7 @@ class TestEnumeration:
         dims = PlanDims(n_required=150, window_needed=5)
         cands = plan_mod.enumerate_candidates(dims, capacity=256,
                                               window=64, expand=16)
-        assert [c.rung for c in cands] == [(256, 64, 16)] * 2
+        assert [c.rung for c in cands] == [(256, 64, 16)]
 
     def test_keyed_dims_enumerate_batch_ladder(self):
         dims = PlanDims(n_required=500, n_crashed=0, window_needed=8,
@@ -137,6 +137,18 @@ class TestFootprint:
                       mesh_axis=8)
         fp = plan_mod.footprint(c)
         assert fp["per-device-bytes"] < fp["total-bytes"]
+
+    def test_sharded_footprint_counts_the_carry_as_input(self):
+        # a sharded search runs the segment executable, which takes the
+        # carry in as well as giving it back: same bytes as a segment
+        shape = dict(capacity=4096, window=32, expand=512, unroll=1,
+                     breq=1024, crw=8)
+        seg = plan_mod.footprint(Candidate(kind="segment", **shape))
+        sh = plan_mod.footprint(Candidate(kind="sharded", mesh_axis=8,
+                                          **shape))
+        assert sh["total-bytes"] == seg["total-bytes"]
+        assert (sh["total-bytes"] - sh["cols-bytes"] - sh["grid-bytes"]
+                - sh["sort-bytes"]) == 3 * sh["carry-bytes"]
 
 
 # ---------------------------------------------------------------------------
@@ -234,6 +246,27 @@ class TestZeroCompile:
         assert any(c.get("cost", {}).get("flops", 0) > 0
                    for c in rep["candidates"])
 
+    def test_sharded_candidate_traces_the_pool_axis_segment(
+            self, monkeypatch):
+        import jax
+        from jax.sharding import Mesh
+        seen = []
+        real = T._jit_segment
+
+        def spy(*a, **k):
+            seen.append(a[5:6])
+            return real(*a, **k)
+
+        monkeypatch.setattr(T, "_jit_segment", spy)
+        plan_mod._TRACE_MEMO.clear()
+        mesh = Mesh(np.array(jax.devices()[:2]), (T.POOL_AXIS,))
+        rep = plan_mod.analyze(PlanDims(n_required=100, window_needed=6),
+                               kernel=kernel_spec_for(CASRegister()),
+                               mesh=mesh, trace=True, kinds=("sharded",))
+        (row,) = rep["candidates"]
+        assert row["kind"] == "sharded" and row["traced"] is True
+        assert seen == [(T.POOL_AXIS,)]
+
     def test_trace_memoized_per_bucket(self):
         plan_mod._TRACE_MEMO.clear()
         dims = PlanDims(n_required=64, window_needed=4)
@@ -270,9 +303,8 @@ class TestGate:
                 raise AssertionError(f"{name} invoked")
             return f
 
-        monkeypatch.setattr(T, "_jit_single", bomb("_jit_single"))
-        monkeypatch.setattr(T, "_jit_segment", bomb("_jit_segment"))
-        monkeypatch.setattr(T, "_jit_batch", bomb("_jit_batch"))
+        for name in ("_jit_segment", "_jit_batch", "_jit_batch_segment"):
+            monkeypatch.setattr(T, name, bomb(name))
         return fired
 
     def test_oversized_capacity_rejected_before_jit(self, monkeypatch):
@@ -286,12 +318,15 @@ class TestGate:
         assert any(f.rule == "PLAN-OOM" for f in ei.value.findings)
 
     def test_monolithic_path_gated_too(self, monkeypatch):
+        # one monolithic segment covering the whole search, and the
+        # shortest segments, meet the same gate
         monkeypatch.setenv("JTPU_PLAN_BYTES_LIMIT", "200000")
         fired = self._forbid_jit(monkeypatch)
-        with pytest.raises(PlanRejectedError):
-            T.check_history_tpu(_history(), CASRegister(),
-                                capacity=16384, window=32,
-                                segment_iters=0)
+        for seg in (T.DEFAULT_SEGMENT_ITERS, 1):
+            with pytest.raises(PlanRejectedError):
+                T.check_history_tpu(_history(), CASRegister(),
+                                    capacity=16384, window=32,
+                                    segment_iters=seg)
         assert fired == []
 
     def test_indivisible_mesh_rejected_before_jit(self, monkeypatch):
@@ -309,18 +344,22 @@ class TestGate:
         dims = PlanDims(n_required=2 ** 30, window_needed=4)
         with pytest.raises(PlanRejectedError) as ei:
             plan_mod.gate_ladder(dims, kernel, ((64, 32, 8),),
-                                 kind="single", explicit=True)
+                                 kind="segment", explicit=True)
         assert "PLAN-INT32-OVERFLOW" in str(ei.value)
         assert fired == []
 
     def test_gate_filters_to_cheapest_valid_rung(self, monkeypatch,
                                                  no_limit):
+        # gate_ladder without derate (as the keyed batch calls it)
+        # drops the rungs that cannot fit and selects the cheapest fit
         monkeypatch.setenv("JTPU_PLAN_BYTES_LIMIT", "1000000")
-        r = T.check_history_tpu(_history(), CASRegister(),
-                                segment_iters=0)
-        assert r["valid"] is True
-        plan = r["plan"]
-        assert plan["selected"].startswith("single ")
+        p, kernel = pack_with_init(_history(), CASRegister())
+        ladder = T._ladder_for(T._window_needed(p))
+        kept, plan = plan_mod.gate_ladder(p, kernel, ladder,
+                                          kind="segment", explicit=False)
+        assert kept == ladder[:len(kept)] and 0 < len(kept) < len(ladder)
+        cap, win, exp = kept[0]
+        assert plan["selected"].startswith(f"segment {cap}/{win}/{exp} ")
         assert plan["rejected"]  # the big rungs could not fit 1 MB
         assert all("PLAN-OOM" in c["rules"] for c in plan["rejected"])
 

@@ -703,7 +703,7 @@ class TestTraceCLISurfaces:
 
 
 # ---------------------------------------------------------------------------
-# Satellites: ring-drop counter, HELP escaping, bench-gate attribution
+# Satellites: ring-drop counter, HELP escaping, metric totals
 # ---------------------------------------------------------------------------
 
 
@@ -739,34 +739,3 @@ class TestSatellites:
         h.observe(9.0, phase="compile", kind="x")
         t = h.total(phase="execute")
         assert t["count"] == 2 and t["sum"] == pytest.approx(2.5)
-
-    def test_bench_gate_attribution(self, tmp_path):
-        import sys
-        sys.path.insert(0, os.path.join(REPO, "tools"))
-        import bench_gate
-        base = {"value": 1.0, "cold_s": 10.0, "platform": "cpu",
-                "compile_s": 8.0, "execute_s": 1.0, "transfer_mb": 5.0,
-                "compile": {"cold_compile_s": 8.0,
-                            "warm_execute_s": 1.0}}
-        for i in range(1, 4):
-            with open(tmp_path / f"BENCH_r{i:02d}.json", "w") as f:
-                json.dump({"n": i, "parsed": dict(base)}, f)
-        # round 4 regresses: cold_s triples, driven by compile_s
-        bad = dict(base, cold_s=45.0, compile_s=40.0,
-                   compile={"cold_compile_s": 40.0,
-                            "warm_execute_s": 1.0})
-        with open(tmp_path / "BENCH_r04.json", "w") as f:
-            json.dump({"n": 4, "parsed": bad}, f)
-        doc = bench_gate.gate(str(tmp_path))
-        assert doc["ok"] is False
-        att = doc["attribution"]
-        assert att, "regression carries no attribution"
-        assert att[0]["axis"] in ("compile_s", "compile.cold_compile_s")
-        assert att[0]["ratio"] == pytest.approx(5.0)
-        execs = [a for a in att if a["axis"] == "execute_s"]
-        assert execs and execs[0]["ratio"] == pytest.approx(1.0)
-        # a clean trajectory carries none
-        with open(tmp_path / "BENCH_r04.json", "w") as f:
-            json.dump({"n": 4, "parsed": dict(base)}, f)
-        doc = bench_gate.gate(str(tmp_path))
-        assert doc["ok"] is True and "attribution" not in doc

@@ -17,9 +17,11 @@ jax = pytest.importorskip("jax")
 
 from jepsen_tpu import resilience
 from jepsen_tpu.checker import UNKNOWN
+from jepsen_tpu.checker import tpu as tpu_mod
 from jepsen_tpu.checker.tpu import (
     DEFAULT_SEGMENT_ITERS, _carry0_host, _segment_config, check_history_tpu)
 from jepsen_tpu.checker.wgl import check_packed
+from jepsen_tpu.history import History
 from jepsen_tpu.models import CASRegister
 from jepsen_tpu.models.core import CAS_REGISTER_KERNEL
 from jepsen_tpu.ops.encode import pack_with_init
@@ -90,22 +92,71 @@ class TestSegmentConfig:
         assert _segment_config(None) == DEFAULT_SEGMENT_ITERS
         monkeypatch.setenv("JTPU_SEGMENT_ITERS", "64")
         assert _segment_config(None) == 64
-        monkeypatch.setenv("JTPU_SEGMENT_ITERS", "0")
-        assert _segment_config(None) is None
-        monkeypatch.setenv("JTPU_SEGMENT_ITERS", "nope")
-        with pytest.raises(ValueError):
-            _segment_config(None)
 
     def test_argument_wins(self, monkeypatch):
         monkeypatch.setenv("JTPU_SEGMENT_ITERS", "64")
         assert _segment_config(7) == 7
-        assert _segment_config(0) is None
+
+    @pytest.mark.parametrize("arg,env,shown", [
+        (0, None, "segment_iters must be a positive integer, got 0"),
+        (-3, None, "segment_iters must be a positive integer, got -3"),
+        (None, "0", "JTPU_SEGMENT_ITERS must be a positive integer, "
+                    "got '0'"),
+        (None, "-1", "JTPU_SEGMENT_ITERS must be a positive integer, "
+                     "got '-1'"),
+        (None, "nope", "JTPU_SEGMENT_ITERS must be a positive integer, "
+                       "got 'nope'"),
+    ], ids=["arg-zero", "arg-negative", "env-zero", "env-negative",
+            "env-not-a-number"])
+    def test_rejects_what_is_not_a_positive_count(self, monkeypatch, arg,
+                                                  env, shown):
+        if env is None:
+            monkeypatch.delenv("JTPU_SEGMENT_ITERS", raising=False)
+        else:
+            monkeypatch.setenv("JTPU_SEGMENT_ITERS", env)
+        with pytest.raises(ValueError) as ei:
+            _segment_config(arg)
+        assert str(ei.value) == shown
+
+    @pytest.mark.parametrize("how", ["argument", "env"])
+    def test_check_history_rejects_zero_before_the_device(
+            self, monkeypatch, how):
+        h = simulate_register_history(30, n_procs=3, n_vals=4, seed=1)
+
+        def boom(*a, **k):
+            raise AssertionError("a search executable was built")
+
+        monkeypatch.setattr(tpu_mod, "_jit_segment", boom)
+        kw = {}
+        if how == "argument":
+            kw["segment_iters"] = 0
+        else:
+            monkeypatch.setenv("JTPU_SEGMENT_ITERS", "0")
+        with pytest.raises(ValueError, match="got '?0'?$"):
+            check_history_tpu(h, CASRegister(), **kw)
+
+
+def _wgl_valid(h):
+    p, kernel = _packed(h)
+    return check_packed(p, kernel)["valid"]
+
+
+def _phantom_read(h):
+    """``h`` with its first ok read answering 99, a value no op wrote:
+    refuted whatever the interleaving."""
+    rows = list(h)
+    i = next(i for i, o in enumerate(rows)
+             if o.type == "ok" and o.f == "read" and o.value is not None)
+    rows[i] = rows[i].replace(value=99)
+    return History.of(rows)
 
 
 class TestSegmentedEqualsMonolithic:
-    """The restructured search (host loop of device segments) must be
-    bit-identical in verdicts and level counts to the single
-    while_loop — the body sequence is the same computation."""
+    """Any segment length runs the same search: small segments (many
+    barriers, the carry round-tripping through the host each time)
+    against one monolithic segment covering the whole search
+    (DEFAULT_SEGMENT_ITERS), and both against the host WGL engine's
+    verdict."""
 
     def test_differential_random_histories(self, monkeypatch):
         import random
@@ -114,25 +165,41 @@ class TestSegmentedEqualsMonolithic:
             h = simulate_register_history(
                 120, n_procs=4, n_vals=4, seed=100 + i,
                 crash_p=0.05, overlap_p=0.5)
-            monkeypatch.setenv("JTPU_SEGMENT_ITERS", "0")
-            mono = check_history_tpu(h, CASRegister())
-            monkeypatch.setenv("JTPU_SEGMENT_ITERS",
-                               str(rng.choice((3, 9, 17))))
-            seg = check_history_tpu(h, CASRegister())
-            assert seg["valid"] == mono["valid"]
-            assert seg["levels"] == mono["levels"]
-            assert seg["rung"] == mono["rung"]
+            one = check_history_tpu(
+                h, CASRegister(), segment_iters=DEFAULT_SEGMENT_ITERS)
+            seg = check_history_tpu(
+                h, CASRegister(), segment_iters=rng.choice((1, 3, 9, 17)))
+            assert one["segments"] == 1
+            assert seg["valid"] == one["valid"] == _wgl_valid(h)
+            assert seg["levels"] == one["levels"]
+            assert seg["rung"] == one["rung"]
             assert seg["segments"] >= 1
 
     def test_refutation_evidence_identical(self, monkeypatch):
         h = wide_history(16, 2, seed=5, corrupt=True)
-        monkeypatch.setenv("JTPU_SEGMENT_ITERS", "0")
-        mono = check_history_tpu(h, CASRegister())
-        monkeypatch.setenv("JTPU_SEGMENT_ITERS", "6")
-        seg = check_history_tpu(h, CASRegister())
-        assert mono["valid"] is False and seg["valid"] is False
-        for k in ("max-linearized-prefix", "final-states", "levels"):
-            assert seg.get(k) == mono.get(k), k
+        one = check_history_tpu(h, CASRegister(),
+                                segment_iters=DEFAULT_SEGMENT_ITERS)
+        seg = check_history_tpu(h, CASRegister(), segment_iters=6)
+        assert one["valid"] is False and seg["valid"] is False
+        assert _wgl_valid(h) is False
+        for k in ("max-linearized-prefix", "final-states", "levels",
+                  "rung"):
+            assert seg.get(k) == one.get(k), k
+
+    @pytest.mark.parametrize("refuted", [False, True],
+                             ids=["valid", "refuted"])
+    @pytest.mark.parametrize("seg", [1, 3, 9, 17, DEFAULT_SEGMENT_ITERS])
+    def test_matches_the_host_engine(self, seg, refuted):
+        h = simulate_register_history(90, n_procs=4, n_vals=4, seed=31,
+                                      crash_p=0.03, overlap_p=0.5)
+        if refuted:
+            h = _phantom_read(h)
+        want = _wgl_valid(h)
+        assert want is (not refuted)
+        r = check_history_tpu(h, CASRegister(), segment_iters=seg)
+        assert r["valid"] is want
+        assert r["segment-iters"] == seg
+        assert r["segments"] >= -(-r["levels"] // seg)
 
     def test_result_carries_resilience_keys(self):
         h = simulate_register_history(60, n_procs=3, n_vals=4, seed=1)

@@ -90,7 +90,7 @@ class TestEngine:
         kid = T._kernel_key(kernel)
         eng = Engine("evict-test", max_entries=2)
         for cap in (8, 16, 32):
-            eng.jit_single(kid, cap, 32, 4, 1)
+            eng.jit_segment(kid, cap, 32, 4, 1)
         assert len(eng._fns) == 2
         assert eng.builds == 3
 

@@ -442,7 +442,8 @@ def scenario_malformed_history(seed):
 
     # Any jit-factory call on a rejected history is a scenario failure.
     compiled = []
-    real = (tpu._jit_single, tpu._jit_segment, tpu._jit_batch)
+    factories = ("_jit_segment", "_jit_batch", "_jit_batch_segment")
+    real = {name: getattr(tpu, name) for name in factories}
 
     def _traced(name):
         def f(*a, **k):
@@ -453,9 +454,8 @@ def scenario_malformed_history(seed):
 
     details = []
     ok = True
-    tpu._jit_single = _traced("_jit_single")
-    tpu._jit_segment = _traced("_jit_segment")
-    tpu._jit_batch = _traced("_jit_batch")
+    for name in factories:
+        setattr(tpu, name, _traced(name))
     try:
         for label, fn, want_rule in corruptions:
             bad = fn(list(h))
@@ -474,7 +474,8 @@ def scenario_malformed_history(seed):
                     ok = False
                     details.append(f"{label}: wrong rule in {e}")
     finally:
-        (tpu._jit_single, tpu._jit_segment, tpu._jit_batch) = real
+        for name, fn in real.items():
+            setattr(tpu, name, fn)
     if compiled:
         ok = False
         details.append(f"jit fired: {compiled}")
@@ -874,7 +875,8 @@ def scenario_plan_rejects(seed):
                        f"{test['results'].get('valid')}")
 
     compiled = []
-    real = (tpu._jit_single, tpu._jit_segment, tpu._jit_batch)
+    factories = ("_jit_segment", "_jit_batch", "_jit_batch_segment")
+    real = {name: getattr(tpu, name) for name in factories}
 
     def _traced(name):
         def f(*a, **k):
@@ -884,9 +886,8 @@ def scenario_plan_rejects(seed):
 
     details = []
     ok = True
-    tpu._jit_single = _traced("_jit_single")
-    tpu._jit_segment = _traced("_jit_segment")
-    tpu._jit_batch = _traced("_jit_batch")
+    for name in factories:
+        setattr(tpu, name, _traced(name))
     os.environ["JTPU_PLAN_BYTES_LIMIT"] = "200000"
     try:
         # (1) oversized capacity vs the byte budget -> PLAN-OOM
@@ -917,7 +918,8 @@ def scenario_plan_rejects(seed):
                 ok = False
                 details.append(f"mesh: wrong rule in {e}")
     finally:
-        (tpu._jit_single, tpu._jit_segment, tpu._jit_batch) = real
+        for name, fn in real.items():
+            setattr(tpu, name, fn)
         os.environ.pop("JTPU_PLAN_BYTES_LIMIT", None)
     if compiled:
         ok = False

@@ -25,8 +25,7 @@ merged export validated structurally:
 
 Usage: python tools/prof_gate.py [--budget SECONDS]
 Exit code 0 iff the merged trace validates within the budget
-(default 30 s; run next to tools/lint_gate.py and tools/bench_gate.py
-in CI).
+(default 30 s; run next to tools/lint_gate.py in CI).
 """
 
 import argparse

@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """CI serve gate: stand the check daemon up, POST a REAL localkv
 history at it over HTTP, poll the verdict, drain, and exit — inside a
-wall-clock bound (default 30 s, run next to lint_gate / prof_gate /
-bench_gate in CI).
+wall-clock bound (default 30 s, run next to lint_gate / prof_gate in
+CI).
 
 The daemon path (`python -m jepsen_tpu serve --check-daemon`,
 doc/serve.md) crosses five layers — the HTTP front-end, admission
